@@ -37,38 +37,45 @@ def ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref, *,
 
     A = a_ref[h]                                     # scalar (negative)
     x = x_ref[...].astype(jnp.float32)               # (Q, P)
-    dt = dt_ref[...].astype(jnp.float32).reshape(Q)  # (Q,)
+    dt_row = dt_ref[...].astype(jnp.float32)         # (1, Q)
     Bm = b_ref[...].astype(jnp.float32)              # (Q, N)
     Cm = c_ref[...].astype(jnp.float32)              # (Q, N)
 
-    logd = dt * A                                    # (Q,)
-    cum = jnp.cumsum(logd)                           # (Q,)
-    xdt = x * dt[:, None]                            # (Q, P)
+    # Prefix sums and row->column moves as masked (Q, Q) reductions: the
+    # TPU lowering has no cumsum and no (1, Q) <-> (Q, 1) reshape.
+    ri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    causal = ri >= ci
+    diag = ri == ci
+    logd_row = dt_row * A                            # (1, Q)
+    cum = jnp.sum(jnp.where(causal, logd_row, 0.0), axis=1,
+                  keepdims=True)                     # (Q, 1) inclusive
+    cum_row = jnp.sum(jnp.where(diag, cum, 0.0), axis=0,
+                      keepdims=True)                 # (1, Q)
+    total = jnp.sum(logd_row, axis=1, keepdims=True)  # (1, 1) = cum[Q-1]
+    dt = jnp.sum(jnp.where(diag, dt_row, 0.0), axis=1,
+                 keepdims=True)                      # (Q, 1)
+    xdt = x * dt                                     # (Q, P)
 
     # intra-chunk: ((C @ B^T) ∘ L) @ xdt   with L = exp(segsum) lower-tri
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (Q,Q)
-    seg = cum[:, None] - cum[None, :]                # log decay j -> i
-    ri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(ri >= ci, jnp.exp(seg), 0.0)
+    L = jnp.where(causal, jnp.exp(cum - cum_row), 0.0)  # log decay j -> i
     y = jax.lax.dot_general(scores * L, xdt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)       # (Q,P)
 
     # inter-chunk: contribution of the carried state
     state = state_ref[...]                           # (P, N)
-    decay_in = jnp.exp(cum)                          # (Q,)
     y_inter = jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y = y + y_inter * decay_in[:, None]
+    y = y + y_inter * jnp.exp(cum)
 
     # state update: state' = state·exp(sum logd) + (decay_out·xdt)^T @ B
-    total = jnp.exp(cum[Q - 1])
-    decay_out = jnp.exp(cum[Q - 1] - cum)            # (Q,)
-    upd = jax.lax.dot_general(xdt * decay_out[:, None], Bm,
+    decay_out = jnp.exp(total - cum)                 # (Q, 1)
+    upd = jax.lax.dot_general(xdt * decay_out, Bm,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)     # (P,N)
-    state_ref[...] = state * total + upd
+    state_ref[...] = state * jnp.exp(total) + upd
     y_ref[...] = y.astype(y_ref.dtype)
 
 
@@ -89,7 +96,7 @@ def ssd_pallas(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
 
     # kernel-major layouts
     xk = x.transpose(0, 2, 1, 3)                     # (B, H, S, P)
-    dtk = dt.transpose(0, 2, 1)[..., None]           # (B, H, S, 1)
+    dtk = dt.transpose(0, 2, 1)[:, :, None, :]       # (B, H, 1, S)
 
     kernel = functools.partial(ssd_kernel, chunk=chunk, num_chunks=nc)
     y = pl.pallas_call(
@@ -99,8 +106,8 @@ def ssd_pallas(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
             pl.BlockSpec(memory_space=pltpu.SMEM),                 # A (H,)
             pl.BlockSpec((None, None, chunk, P),
                          lambda b, h, ic: (b, h, ic, 0)),          # x
-            pl.BlockSpec((None, None, chunk, 1),
-                         lambda b, h, ic: (b, h, ic, 0)),          # dt
+            pl.BlockSpec((None, None, 1, chunk),
+                         lambda b, h, ic: (b, h, 0, ic)),          # dt
             pl.BlockSpec((None, chunk, N), lambda b, h, ic: (b, ic, 0)),
             pl.BlockSpec((None, chunk, N), lambda b, h, ic: (b, ic, 0)),
         ],

@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs import get_config, list_archs
 from repro.data import DataConfig, make_pipeline
 from repro.checkpoint import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig
@@ -115,6 +116,7 @@ def main(argv=None) -> int:
     ap.add_argument("--microbatches", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     out = train_loop(args.arch, steps=args.steps, batch=args.batch,
                      seq=args.seq, reduced=not args.full,
                      ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

@@ -28,7 +28,6 @@ from jax.sharding import PartitionSpec as P
 from .common import ACTIVATIONS, softmax_fp32
 from .config import ModelConfig
 from .param import ArrayDecl, normal_init
-from ..sharding.compat import shard_map
 from ..sharding.context import current_mesh, data_axes, model_axis
 
 __all__ = ["moe_decls", "moe"]
@@ -216,7 +215,7 @@ def moe(params: dict, x: jax.Array, cfg: ModelConfig):
         sh_ = tuple(rest) if shared is not None else None  # (gate, up, down)
         return fn(x_, ti_, g_, wg_, wu_, wd_, sh_)
 
-    y = shard_map(
+    y = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=tuple(in_specs), out_specs=dspec,
         check_vma=False,

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..sharding.compat import shard_map
 from ..sharding.context import data_axes
 
 __all__ = ["compressed_dp_mean"]
@@ -99,7 +98,7 @@ def compressed_dp_mean(grads, mesh: Mesh):
         return jax.tree.map(one, gs)
 
     specs = jax.tree.map(lambda g: P(*([None] * g.ndim)), grads)
-    return shard_map(
+    return jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(specs,),
         out_specs=specs,

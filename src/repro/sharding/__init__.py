@@ -1,10 +1,9 @@
 """Sharding: logical-axis rules, mesh context, partition specs."""
 
-from .compat import shard_map
 from .context import (current_mesh, data_axes, mesh_context, model_axis,
                       set_current_mesh)
 from .rules import (logical_to_spec, make_rules, spec_tree)
 
-__all__ = ["shard_map", "current_mesh", "set_current_mesh", "mesh_context",
+__all__ = ["current_mesh", "set_current_mesh", "mesh_context",
            "data_axes", "model_axis", "logical_to_spec", "make_rules",
            "spec_tree"]
