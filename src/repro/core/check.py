@@ -55,18 +55,22 @@ __all__ = ["TraceEvent", "TraceRecorder", "Violation", "TraceChecker",
            "PlanConformance", "content_digest"]
 
 
-def content_digest(value: Any) -> str | None:
+def content_digest(value: Any, spans=None) -> str | None:
     """Stable hex digest of a value's content, or None when the value is
     opaque (no reliable byte representation — e.g. objects whose repr
     embeds a memory address, which would make identical re-executions
-    look divergent)."""
+    look divergent).
+
+    With a DScope ``spans`` :class:`~repro.core.obs.Tracer`, each array
+    leaf's ``tobytes()`` (for a device array, the copy to the host) gets a
+    ``d2h`` span under the calling thread's active span."""
     h = hashlib.blake2b(digest_size=16)
-    if _feed(h, value):
+    if _feed(h, value, spans):
         return h.hexdigest()
     return None
 
 
-def _feed(h, value: Any) -> bool:
+def _feed(h, value: Any, spans=None) -> bool:
     if isinstance(value, (bytes, bytearray, memoryview)):
         h.update(b"b")
         h.update(bytes(value))
@@ -80,21 +84,29 @@ def _feed(h, value: Any) -> bool:
         return True
     if isinstance(value, (tuple, list)):
         h.update(b"l%d" % len(value))
-        return all(_feed(h, v) for v in value)
+        return all(_feed(h, v, spans) for v in value)
     if isinstance(value, dict):
         h.update(b"d%d" % len(value))
         try:
             items = sorted(value.items())
         except TypeError:
             return False
-        return all(_feed(h, k) and _feed(h, v) for k, v in items)
+        return all(_feed(h, k, spans) and _feed(h, v, spans)
+                   for k, v in items)
     tobytes = getattr(value, "tobytes", None)   # numpy/jax arrays
     if tobytes is not None:
         try:
+            dtype = getattr(value, "dtype", "")
+            shape = getattr(value, "shape", "")
             h.update(b"a")
-            h.update(repr(getattr(value, "dtype", "")).encode())
-            h.update(repr(getattr(value, "shape", "")).encode())
-            h.update(tobytes())
+            h.update(repr(dtype).encode())
+            h.update(repr(shape).encode())
+            if spans is None:
+                h.update(tobytes())
+                return True
+            with spans.span(f"{dtype}{shape}", "d2h"):
+                raw = tobytes()
+            h.update(raw)
             return True
         except Exception:       # pragma: no cover - exotic array types
             return False

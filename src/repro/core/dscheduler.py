@@ -36,7 +36,7 @@ from __future__ import annotations
 import math
 import threading
 import time
-from contextlib import nullcontext
+from contextlib import ExitStack, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Mapping
 
@@ -477,11 +477,7 @@ class InstanceRun:
                         if lease.cold:
                             with state.lock:
                                 self.report.cold_starts += 1
-                    if containers is not None:
-                        with containers.slot(node):
-                            result = f.fn(**kwargs) if f.fn else {}
-                    else:
-                        result = f.fn(**kwargs) if f.fn else {}
+                    result = self._run_body(node, f, kwargs)
                     if not isinstance(result, Mapping):
                         raise TypeError(
                             f"{fname} must return a mapping of outputs")
@@ -513,6 +509,24 @@ class InstanceRun:
         finally:
             if lease is not None:
                 containers.release(node, self.image(fname), lease)
+
+    def _run_body(self, node: str, f: FunctionSpec,
+                  kwargs: dict[str, Any]) -> Any:
+        """Run the body in one of its node's execution slots.  Traced, a
+        ``slot`` span covers the wait for the slot and an ``exec`` span
+        the body, both under the invoke span."""
+        containers, spans = self.engine.containers, self.spans
+        body = f.fn or (lambda **_: {})
+        slot = (containers.slot(node) if containers is not None
+                else nullcontext())
+        if spans is None:
+            with slot:
+                return body(**kwargs)
+        with ExitStack() as held:
+            with spans.span(f.name, "slot", node=node):
+                held.enter_context(slot)
+            with spans.span(f.name, "exec", node=node):
+                return body(**kwargs)
 
     def _on_complete(self, fname: str) -> None:
         state, wf = self.state, self.wf

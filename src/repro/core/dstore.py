@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import threading
 import time
-from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Callable, Mapping
 
@@ -63,6 +62,14 @@ except Exception:  # pragma: no cover - numpy is optional for sizing
 
 
 def _sizeof(value: Any) -> int:
+    """Bytes of a value: its ``nbytes`` or length, or for a tuple, list or
+    dict (a pytree such as a KV cache) the sum over its sized leaves; 64
+    (metadata only) for an opaque value with no sized leaf."""
+    size = _leaf_bytes(value)
+    return 64 if size is None else size
+
+
+def _leaf_bytes(value: Any) -> int | None:
     try:
         if hasattr(value, "nbytes"):
             return int(value.nbytes)
@@ -71,13 +78,13 @@ def _sizeof(value: Any) -> int:
         if _np is not None and isinstance(value, _np.ndarray):
             return int(value.nbytes)
     except Exception:  # pragma: no cover - best effort sizing
-        pass
-    return 64  # opaque object: metadata-only size
-
-
-# nullcontext is reentrant and stateless, so one shared instance serves
-# every un-instrumented Get.
-_NULL_CTX = nullcontext()
+        return None
+    if isinstance(value, dict):
+        value = value.values()
+    elif not isinstance(value, (tuple, list)):
+        return None
+    sizes = [n for n in map(_leaf_bytes, value) if n is not None]
+    return sum(sizes) if sizes else None
 
 
 def _trace_of(key: str) -> str:
@@ -313,13 +320,16 @@ class DStore:
         """Attach (or detach, with None) a DScope span
         :class:`~repro.core.obs.Tracer`.  Every Get/Put/chunk/evict from
         then on emits a span parented under the calling thread's active
-        span (the function-invocation span the engine activated)."""
+        span (the function-invocation span the engine activated); a Get
+        nests its ``wait`` for the key's metadata, a Put its ``digest``
+        and the digest's per-leaf ``d2h`` spans."""
         self._spans = spans
 
     def attach_metrics(self, registry) -> None:
         """Attach a :class:`~repro.core.obs.MetricsRegistry` for hot-path
-        latency histograms (per-Get/Put) *and* register the pull
-        collectors.  Passing None detaches the push hooks."""
+        latency histograms (per stream chunk) *and* register the pull
+        collectors.  Passing None detaches the push hooks.  Get and Put
+        latency is timed by their DScope spans (:meth:`attach_spans`)."""
         self._metrics = registry
         if registry is not None:
             self.register_metrics(registry)
@@ -353,15 +363,21 @@ class DStore:
         spans = self._spans
         if spans is None:
             return self._put(node, key, value)
-        sp = spans.start(key, "put", node=node, size=_sizeof(value))
-        try:
+        # Activated so the digest and its per-leaf spans nest under it.
+        with spans.span(key, "put", node=node, size=_sizeof(value)):
             return self._put(node, key, value)
-        finally:
-            spans.end(sp)
+
+    def _digest(self, key: str, value: Any) -> str | None:
+        """The Put's content digest; traced, a ``digest`` span over it."""
+        spans = self._spans
+        if spans is None:
+            return content_digest(value)
+        with spans.span(key, "digest"):
+            return content_digest(value, spans)
 
     def _put(self, node: str, key: str, value: Any) -> None:
         store = self.stores[node]
-        digest = content_digest(value)
+        digest = self._digest(key, value)
         tracer = self._tracer
         with self._write_lock:
             meta = self.directory.peek(key)
@@ -375,13 +391,13 @@ class DStore:
                     return              # duplicate write: first-writer-wins
             # Recorded before the bytes land so the trace's availability
             # event precedes any Get that could observe them.
+            size = _sizeof(value)
             if tracer is not None:
-                tracer.record("put", key, node, size=_sizeof(value),
-                              digest=digest)
+                tracer.record("put", key, node, size=size, digest=digest)
             store.write(key, value)
             # Metadata publish is what wakes consumers; in the real system it
             # is asynchronous w.r.t. the producer container, here just cheap.
-            self.directory.publish(key, _sizeof(value), node, digest=digest)
+            self.directory.publish(key, size, node, digest=digest)
             self._note_peak()
         self.streams.notify_plain(key)   # wake get_stream fallbacks
 
@@ -392,40 +408,39 @@ class DStore:
         A replica whose bytes are gone (its Put raced a node failure, so the
         directory record points at a wiped store) is dropped and the wait
         restarts — recovery re-publishes the key and wakes us again.
+
+        Traced, a ``wait`` span under the ``get`` span runs from entry
+        until this thread holds the key's published metadata (at once for
+        a local replica).
         """
         spans = self._spans
-        metrics = self._metrics
-        if spans is None and metrics is None:
+        if spans is None:
             return self._get_recorded(node, key, timeout)
-        t0 = time.monotonic()
-        sp = None
-        if spans is not None:
-            sp = spans.start(key, "chunk" if is_chunk_key(key) else "get",
-                             node=node)
+        sp = spans.start(key, "chunk" if is_chunk_key(key) else "get",
+                         node=node)
+        wait = spans.start(key, "wait", parent=sp, node=node)
         try:
             # Activated so cross-shard hop spans nest under this Get.
-            with spans.activate(sp) if spans is not None else _NULL_CTX:
-                value = self._get_recorded(node, key, timeout)
+            with spans.activate(sp):
+                value = self._get_recorded(
+                    node, key, timeout, woke=lambda: spans.end(wait))
         except BaseException:
-            if sp is not None:
-                spans.end(sp, error=True)
+            spans.end(wait, error=True)
+            spans.end(sp, error=True)
             raise
-        if sp is not None:
-            spans.end(sp, size=_sizeof(value))
-        if metrics is not None:
-            metrics.histogram("dstore_get_seconds").observe(
-                time.monotonic() - t0)
+        spans.end(sp, size=_sizeof(value))
         return value
 
     def _get_recorded(self, node: str, key: str,
-                      timeout: float | None = None) -> Any:
+                      timeout: float | None = None,
+                      woke: Callable[[], None] | None = None) -> Any:
         tracer = self._tracer
         if tracer is None:
-            value = self._get(node, key, timeout)
+            value = self._get(node, key, timeout, woke)
         else:
             tracer.record("get_block", key, node)
             try:
-                value = self._get(node, key, timeout)
+                value = self._get(node, key, timeout, woke)
             except BaseException:
                 tracer.record("get_fail", key, node)
                 raise
@@ -437,17 +452,23 @@ class DStore:
             self._plan_note_read(key)
         return value
 
-    def _get(self, node: str, key: str,
-             timeout: float | None = None) -> Any:
+    def _get(self, node: str, key: str, timeout: float | None = None,
+             woke: Callable[[], None] | None = None) -> Any:
+        """``woke`` (traced Gets) is called once this thread holds the
+        key's metadata: a local replica, or the directory's record."""
         store = self.stores[node]
         deadline = None if timeout is None else time.monotonic() + timeout
         while True:
             if store.has(key):
+                if woke is not None:
+                    woke()
                 return store.read(key)
             remaining = None
             if deadline is not None:
                 remaining = max(deadline - time.monotonic(), 0.0)
             meta = self.directory.wait(key, remaining)
+            if woke is not None:
+                woke()
             if store.has(key):
                 return store.read(key)
             try:

@@ -15,13 +15,15 @@ through:
 * :class:`MetricsRegistry` — thread-safe counters / gauges / histograms
   with label sets.  Subsystems register *collectors* (pull-style scrape
   callbacks, zero hot-path cost) via their ``register_metrics`` methods;
-  hot-path latency histograms (per-Get, per-chunk) are pushed only when a
+  hot-path latency histograms (per stream chunk) are pushed only when a
   registry is *attached* (``attach_metrics``, mirroring the DCheck
   ``attach_tracer`` zero-cost-when-off pattern).
 * :class:`Tracer` / :class:`Span` — per-request span trees:
-  request → function invocation → container acquire → per-Get/Put →
-  per-chunk stream transfer → cross-shard hop.  Ordering comes from a
-  logical clock (optionally shared with DCheck's
+  request → function invocation → container acquire / execution slot /
+  body → per-Get (its wait for the key) / per-Put (its digest, and the
+  device → host copy of each array leaf) → per-chunk stream transfer →
+  cross-shard hop; beside the tree, each arrival's admission lag.
+  Ordering comes from a logical clock (optionally shared with DCheck's
   :class:`~repro.core.check.TraceRecorder` so spans and invariant events
   interleave consistently); durations come from an injectable clock —
   wall clock in the threaded engine, ``env.now`` in the simulator.
@@ -301,7 +303,8 @@ class Span:
     parent: int | None
     trace: str
     name: str
-    kind: str         # request | invoke | acquire | get | put | chunk |
+    kind: str         # request | admit | invoke | acquire | slot | exec |
+    #                   get | wait | put | digest | d2h | chunk |
     #                   chunk_put | hop | evict
     start: float
     seq: int
@@ -342,6 +345,12 @@ class Tracer:
 
     ``clock`` is injectable: ``time.monotonic`` (default) in the threaded
     engine, ``lambda: env.now`` in the simulator (:meth:`set_clock`).
+    Every span of the threaded engine, DServe and DStore stays on the
+    default ``time.monotonic``: a profiler trace tied to that clock (one
+    annotation opened at a known ``time.monotonic`` reading) puts device
+    activity on the same axis, so device idle gaps can be joined with the
+    spans open during them.  ``start(..., start=t)`` backdates a span to a
+    reading ``t`` of the same clock (an arrival's due time).
     ``recorder`` shares DCheck's :class:`~repro.core.check.TraceRecorder`
     logical clock so span ``seq`` values interleave consistently with
     invariant-trace events; without one the tracer counts on its own.
@@ -370,7 +379,7 @@ class Tracer:
     # -- span lifecycle ----------------------------------------------------
     def start(self, name: str, kind: str = "span", *,
               parent: Any = _USE_CURRENT, trace: str | None = None,
-              **attrs: Any) -> Span:
+              start: float | None = None, **attrs: Any) -> Span:
         if parent is _USE_CURRENT:
             parent = self.current()
         with self._lock:
@@ -380,7 +389,8 @@ class Tracer:
             trace = parent.trace if parent is not None else ""
         return Span(id=sid, parent=parent.id if parent else None,
                     trace=trace, name=name, kind=kind,
-                    start=self._clock(), seq=self._tick(), attrs=attrs)
+                    start=self._clock() if start is None else start,
+                    seq=self._tick(), attrs=attrs)
 
     def end(self, span: Span | None, **attrs: Any) -> None:
         """Close a span (idempotent; attrs merge in)."""

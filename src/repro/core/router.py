@@ -414,7 +414,7 @@ class ShardedDStore(DStore):
         home = self._home_for_put(node, key)
         shard = self.shards[home]
         store = self.stores[node]
-        digest = content_digest(value)
+        digest = self._digest(key, value)
         tracer = self._tracer
         with self._write_lock:
             meta = shard.peek(key)
@@ -426,11 +426,12 @@ class ShardedDStore(DStore):
                         f"first writer's content: DStore data is immutable")
                 if store.has(key):
                     return          # duplicate write: first-writer-wins
+            size = _sizeof(value)
             if tracer is not None:
-                tracer.record("put", key, node, size=_sizeof(value),
-                              digest=digest, src=home)
+                tracer.record("put", key, node, size=size, digest=digest,
+                              src=home)
             store.write(key, value)
-            shard.publish(key, _sizeof(value), node, digest=digest)
+            shard.publish(key, size, node, digest=digest)
             self._note_peak()
         self.streams.notify_plain(key)
 
@@ -450,7 +451,8 @@ class ShardedDStore(DStore):
             self._note_peak()
         self.streams.publish_chunk(key, idx, len(chunk))
 
-    def _get(self, node: str, key: str, timeout: float | None = None):
+    def _get(self, node: str, key: str, timeout: float | None = None,
+             woke=None):
         store = self.stores[node]
         table = self.tables[node]
         deadline = None if timeout is None else time.monotonic() + timeout
@@ -458,6 +460,8 @@ class ShardedDStore(DStore):
         home: str | None = None
         while True:
             if store.has(key):
+                if woke is not None:
+                    woke()
                 self._note_local_hit(node, key)
                 return store.read(key)
             if home is None:
@@ -491,6 +495,8 @@ class ShardedDStore(DStore):
                     self.coordinator.sync(table)
                     home = auth
                 continue
+            if woke is not None:
+                woke()
             value = self._pull(node, key, meta, home, hops=1 + wrong)
             if value is not _MISSING:
                 return value

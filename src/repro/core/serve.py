@@ -669,10 +669,11 @@ class DServe:
     with pull collectors (containers, store, routing) — ``ServeReport``
     is built from it, and ``self.metrics.collect()`` dumps every counter
     from one source.  Passing your own ``metrics`` registry additionally
-    enables the push-side hot-path histograms (per-Get / per-chunk
-    latency); passing a ``spans`` :class:`~repro.core.obs.Tracer` records
-    per-request span trees (request → invoke → acquire → Get/Put → chunk
-    → hop).  Both default to off-path: a plain DServe pays nothing.
+    enables the push-side hot-path histograms (per-chunk latency);
+    passing a ``spans`` :class:`~repro.core.obs.Tracer` records
+    per-request span trees (request → invoke → acquire / slot / exec →
+    Get/Put → chunk → hop) and each arrival's ``admit`` span (due time →
+    launch).  Both default to off-path: a plain DServe pays nothing.
     """
 
     def __init__(self, wf, *, n_nodes: int = 2, pattern: str = "dataflow",
@@ -858,6 +859,12 @@ class DServe:
         from .dscheduler import InstanceRun
 
         def launch(i: int, stat: InstanceStat) -> None:
+            # How late the arrival loop (or the admission queue) launches
+            # the instance after it fell due; not part of stat.latency.
+            if self.spans is not None:
+                self.spans.end(self.spans.start(
+                    stat.instance, "admit", parent=None,
+                    trace=stat.instance, start=t0 + stat.arrival))
             payload = inputs(i) if callable(inputs) else inputs
             run = InstanceRun(self.engine, self.wf, payload,
                               store=self.store, instance=stat.instance,
