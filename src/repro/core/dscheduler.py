@@ -67,6 +67,20 @@ def dataflow_next_frontier(wf: Workflow, finished: str) -> list[str]:
     return list(dict.fromkeys(out))
 
 
+def _block_until_ready(value: Any) -> None:
+    """Wait for every leaf of a tuple/list/dict pytree that is a device
+    future (duck-typed: it has ``block_until_ready``)."""
+    if isinstance(value, dict):
+        value = value.values()
+    elif not isinstance(value, (tuple, list)):
+        ready = getattr(value, "block_until_ready", None)
+        if ready is not None:
+            ready()
+        return
+    for leaf in value:
+        _block_until_ready(leaf)
+
+
 @dataclass
 class RunReport:
     outputs: dict[str, Any]
@@ -329,10 +343,6 @@ class InstanceRun:
         if not state.all_done.is_set():
             raise TimeoutError("workflow did not complete")
         report = self.report
-        report.wall_time = time.monotonic() - self.t0
-        report.per_function = dict(state.completed)
-        report.transfers = self.engine.transport.transfers
-        report.bytes_moved = self.engine.transport.bytes_moved
         # Gather every *sink* datum (produced but never consumed) — exit
         # functions' outputs plus by-products like metrics/final state.
         consumed = {k for f in wf.functions.values() for k in f.inputs}
@@ -342,6 +352,13 @@ class InstanceRun:
                     report.outputs[k] = self.store.get(
                         self.engine.nodes[0], self.ns(k),
                         timeout=self.engine.get_timeout)
+        # A Put publishes a device array before its producer has run, so
+        # the response exists only once every such leaf is ready.
+        _block_until_ready(report.outputs)
+        report.wall_time = time.monotonic() - self.t0
+        report.per_function = dict(state.completed)
+        report.transfers = self.engine.transport.transfers
+        report.bytes_moved = self.engine.transport.bytes_moved
         return report
 
     def evict(self) -> None:

@@ -42,8 +42,8 @@ class ImmutabilityError(ValueError):
     First-writer-wins duplicate safety (§3.3) presumes deterministic
     functions: a straggler re-execution must produce the *same bytes* as
     the original, otherwise which copy a consumer sees depends on replica
-    choice.  The directory records a content digest at first publish and
-    rejects any later publish whose digest disagrees."""
+    choice.  A co-write digests the first value (once; the directory keeps
+    it) and its own, and is rejected when they disagree."""
 
 
 # stream.py lazily imports GetTimeout, so this import must come after it.
@@ -99,8 +99,10 @@ class _Meta:
     key: str
     size: int
     locations: dict[str, int] = field(default_factory=dict)
-    digest: str | None = None     # content digest of first publish (None =
-    #                               opaque value, equality unverifiable)
+    # Content digest of the first value, set by the first co-write (or by
+    # a DCheck recorder's eager digest); None = not needed yet, or an
+    # opaque value whose equality is unverifiable.
+    digest: str | None = None
 
 
 class DataDirectoryService:
@@ -308,6 +310,7 @@ class DStore:
         self._plan_lock = threading.Lock()
         self._plan_reads: dict[str, int] = {}
         self._peak_bytes = 0
+        self._cowrite_checks = 0       # under _write_lock
 
     def attach_tracer(self, tracer: TraceRecorder | None) -> None:
         """Attach (or detach, with None) a :class:`TraceRecorder`.  Every
@@ -322,7 +325,8 @@ class DStore:
         then on emits a span parented under the calling thread's active
         span (the function-invocation span the engine activated); a Get
         nests its ``wait`` for the key's metadata, a Put its ``digest``
-        and the digest's per-leaf ``d2h`` spans."""
+        and the digest's per-leaf ``d2h`` spans (a co-write's, or every
+        Put's with a DCheck recorder attached)."""
         self._spans = spans
 
     def attach_metrics(self, registry) -> None:
@@ -336,14 +340,16 @@ class DStore:
 
     def register_metrics(self, registry) -> None:
         """Register pull-style collectors only (no hot-path cost): per-node
-        resident/peak bytes and transport traffic, scraped at
-        ``registry.collect()`` time."""
+        resident/peak bytes, co-writes whose contents were compared, and
+        transport traffic, scraped at ``registry.collect()`` time."""
         def _scrape() -> None:
             for node, s in self.stores.items():
                 registry.gauge("dstore_resident_bytes",
                                node=node).set(s.resident_bytes)
                 registry.gauge("dstore_peak_resident_bytes",
                                node=node).set(s.peak_bytes)
+            registry.counter("dstore_cowrite_checks").set(
+                self._cowrite_checks)
             registry.counter("transport_bytes_moved").set(
                 self.transport.bytes_moved)
             registry.counter("transport_transfers").set(
@@ -354,11 +360,13 @@ class DStore:
     def put(self, node: str, key: str, value: Any) -> None:
         """Create data with the given key (immutable; §3.3).
 
-        Duplicate (straggler) co-writes are safe only because functions are
-        deterministic — the directory verifies it: a co-write whose content
-        digest diverges from the first publish raises
-        :class:`ImmutabilityError` instead of silently registering a second
-        replica with different bytes.
+        The value is published as it is: nothing waits for a device
+        producer or copies it to the host.  Duplicate (straggler)
+        co-writes are safe only because functions are deterministic — a
+        co-write verifies it: its content digest is compared with the
+        first value's, and a divergence raises :class:`ImmutabilityError`
+        instead of silently registering a second replica with different
+        bytes.
         """
         spans = self._spans
         if spans is None:
@@ -368,7 +376,7 @@ class DStore:
             return self._put(node, key, value)
 
     def _digest(self, key: str, value: Any) -> str | None:
-        """The Put's content digest; traced, a ``digest`` span over it."""
+        """Content digest of ``value``; traced, a ``digest`` span over it."""
         spans = self._spans
         if spans is None:
             return content_digest(value)
@@ -376,30 +384,57 @@ class DStore:
             return content_digest(value, spans)
 
     def _put(self, node: str, key: str, value: Any) -> None:
+        self._put_into(self.directory, node, key, value)
+
+    def _put_into(self, directory: DataDirectoryService, node: str,
+                  key: str, value: Any, **where: str) -> None:
+        """A Put's body against the directory that holds ``key``'s record
+        (``where``: extra fields of DCheck's ``put`` event)."""
         store = self.stores[node]
-        digest = self._digest(key, value)
         tracer = self._tracer
+        # DCheck's put events carry the digest its checks compare.
+        digest = self._digest(key, value) if tracer is not None else None
         with self._write_lock:
-            meta = self.directory.peek(key)
+            meta = directory.peek(key)
             if meta is not None:
-                if (digest is not None and meta.digest is not None
-                        and meta.digest != digest):
-                    raise ImmutabilityError(
-                        f"put({key!r}) from {node!r} diverges from the "
-                        f"first writer's content: DStore data is immutable")
+                digest = self._check_cowrite(node, key, value, meta, digest)
                 if store.has(key):
                     return              # duplicate write: first-writer-wins
             # Recorded before the bytes land so the trace's availability
             # event precedes any Get that could observe them.
             size = _sizeof(value)
             if tracer is not None:
-                tracer.record("put", key, node, size=size, digest=digest)
+                tracer.record("put", key, node, size=size, digest=digest,
+                              **where)
             store.write(key, value)
             # Metadata publish is what wakes consumers; in the real system it
             # is asynchronous w.r.t. the producer container, here just cheap.
-            self.directory.publish(key, size, node, digest=digest)
+            directory.publish(key, size, node, digest=digest)
             self._note_peak()
         self.streams.notify_plain(key)   # wake get_stream fallbacks
+
+    def _check_cowrite(self, node: str, key: str, value: Any, meta: _Meta,
+                       digest: str | None) -> str | None:
+        """A Put found ``key`` already published: compare its content with
+        the first value's, read from a replica the record lists and
+        digested once (the record keeps it).  Returns the new value's
+        digest; raises :class:`ImmutabilityError` on a divergence.  Runs
+        under ``_write_lock``, which every store mutation holds, so the
+        listed replicas' bytes are there."""
+        self._cowrite_checks += 1
+        if meta.digest is None:
+            first = next((n for n in meta.locations
+                          if self.stores[n].has(key)), None)
+            if first is not None:
+                meta.digest = self._digest(key, self.stores[first].read(key))
+        if digest is None:
+            digest = self._digest(key, value)
+        if (digest is not None and meta.digest is not None
+                and meta.digest != digest):
+            raise ImmutabilityError(
+                f"put({key!r}) from {node!r} diverges from the "
+                f"first writer's content: DStore data is immutable")
+        return digest
 
     def get(self, node: str, key: str,
             timeout: float | None = None) -> Any:

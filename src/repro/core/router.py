@@ -44,8 +44,8 @@ import threading
 import time
 from typing import TYPE_CHECKING, Iterable, Mapping
 
-from .dstore import (DStore, DataDirectoryService, GetTimeout,
-                     ImmutabilityError, Transport, _sizeof, _trace_of)
+from .dstore import (DStore, DataDirectoryService, GetTimeout, Transport,
+                     _trace_of)
 from .check import content_digest
 from .partition import stage_node
 from .stream import base_key, chunk_key
@@ -412,28 +412,7 @@ class ShardedDStore(DStore):
     # sharded stores are instrumented identically to the single store.
     def _put(self, node: str, key: str, value) -> None:
         home = self._home_for_put(node, key)
-        shard = self.shards[home]
-        store = self.stores[node]
-        digest = self._digest(key, value)
-        tracer = self._tracer
-        with self._write_lock:
-            meta = shard.peek(key)
-            if meta is not None:
-                if (digest is not None and meta.digest is not None
-                        and meta.digest != digest):
-                    raise ImmutabilityError(
-                        f"put({key!r}) from {node!r} diverges from the "
-                        f"first writer's content: DStore data is immutable")
-                if store.has(key):
-                    return          # duplicate write: first-writer-wins
-            size = _sizeof(value)
-            if tracer is not None:
-                tracer.record("put", key, node, size=size, digest=digest,
-                              src=home)
-            store.write(key, value)
-            shard.publish(key, size, node, digest=digest)
-            self._note_peak()
-        self.streams.notify_plain(key)
+        self._put_into(self.shards[home], node, key, value, src=home)
 
     def _put_chunk(self, node: str, key: str, idx: int,
                    chunk: bytes) -> None:

@@ -9,7 +9,7 @@ Layers under test (``repro.core.obs``):
   Chrome ``trace_event`` exporters round-trip.  The spans that break a
   request down where the work happens: ``admit`` (due time -> launch),
   ``slot`` and ``exec`` under each invoke, ``wait`` under each Get,
-  ``digest`` and its per-leaf ``d2h`` under each Put; none of
+  ``digest`` and its per-leaf ``d2h`` under a co-writing Put; none of
   them exists without a tracer.
 * :func:`attribute` — hand-built spans against a hand-built plan doc
   give exactly the drifts we constructed.
@@ -276,11 +276,13 @@ def test_slot_span_covers_the_wait_for_the_other_body():
 
 def test_wait_ends_after_the_producers_put_publishes():
     """A consumer blocked in Get holds the key's metadata only once the
-    Put has published it: after the Put's digest, which comes before its
-    publish.  (The Put span itself closes a few microseconds after the
-    publish, so its end and the wait's may come in either order.)"""
+    Put has published it.  A first Put digests nothing: its value is
+    published as it is.  (The Put span itself closes a few microseconds
+    after the publish, so its end and the wait's may come in either
+    order.)"""
     tr = Tracer()
     store = DStore(["node0", "node1"])
+    store.attach_tracer(None)       # DCheck's recorder digests every Put
     store.attach_spans(tr)
     got = []
     consumer = threading.Thread(
@@ -294,12 +296,18 @@ def test_wait_ends_after_the_producers_put_publishes():
     by_id = {s.id: s for s in spans}
     (wait,) = [s for s in spans if s.kind == "wait"]
     (put,) = [s for s in spans if s.kind == "put"]
-    (digest,) = [s for s in spans if s.kind == "digest"]
+    assert not [s for s in spans if s.kind in ("digest", "d2h")]
     assert wait.name == put.name == "W#0:k"
     assert by_id[wait.parent].kind == "get"
-    assert by_id[digest.parent] is put
-    assert wait.start < put.start
-    assert wait.end >= digest.end
+    assert wait.start < put.start < wait.end
+    # A co-write digests the first value and its own, under its Put.
+    store.put("node0", "W#0:k", np.arange(64, dtype=np.float32))
+    spans = tr.finished()
+    by_id = {s.id: s for s in spans}
+    cowrite = [s for s in spans if s.kind == "put"][-1]
+    digests = [s for s in spans if s.kind == "digest"]
+    assert len(digests) == 2
+    assert all(by_id[d.parent] is cowrite for d in digests)
     # A local replica: the wait ends at once, before the Get does.
     store.get("node0", "W#0:k")
     spans = tr.finished()
@@ -310,24 +318,33 @@ def test_wait_ends_after_the_producers_put_publishes():
 
 def test_digest_covers_one_d2h_per_array_leaf():
     tr = Tracer()
-    store = DStore(["node0"])
+    store = DStore(["node0", "node1"])
+    store.attach_tracer(None)       # DCheck's recorder digests every Put
     store.attach_spans(tr)
-    value = {"k": np.ones((32, 16), np.float32),
-             "v": (np.zeros(100, np.int8), np.arange(7.0)), "n": 3}
-    store.put("node0", "W#0:cache", value)
+
+    def value():
+        return {"k": np.ones((32, 16), np.float32),
+                "v": (np.zeros(100, np.int8), np.arange(7.0)), "n": 3}
+    store.put("node0", "W#0:cache", value())
+    assert not [s for s in tr.finished() if s.kind in ("digest", "d2h")]
+    assert store.directory.peek("W#0:cache").digest is None
+    # The co-write digests both values: the first from its replica.
+    store.put("node1", "W#0:cache", value())
     spans = tr.finished()
-    (digest,) = [s for s in spans if s.kind == "digest"]
-    d2h = [s for s in spans if s.kind == "d2h"]
-    assert sorted(s.name for s in d2h) == [
-        "float32(32, 16)", "float64(7,)", "int8(100,)"]
-    for s in d2h:
-        assert s.parent == digest.id
-        assert digest.start <= s.start <= s.end <= digest.end
-    assert sum(s.duration for s in d2h) <= digest.duration
+    digests = [s for s in spans if s.kind == "digest"]
+    assert len(digests) == 2
+    for digest in digests:
+        d2h = [s for s in spans if s.kind == "d2h" and s.parent == digest.id]
+        assert sorted(s.name for s in d2h) == [
+            "float32(32, 16)", "float64(7,)", "int8(100,)"]
+        for s in d2h:
+            assert digest.start <= s.start <= s.end <= digest.end
+        assert sum(s.duration for s in d2h) <= digest.duration
+    assert len([s for s in spans if s.kind == "d2h"]) == 6
     # The digest is the one DCheck compares: tracing does not change it.
     from repro.core.check import content_digest
     assert store.directory.peek("W#0:cache").digest == \
-        content_digest(value)
+        content_digest(value())
 
 
 def test_put_size_sums_the_leaves_of_a_pytree():
@@ -393,11 +410,17 @@ def test_no_span_is_created_without_a_tracer(monkeypatch):
     store.put("node0", "k", {"a": np.ones(4)})
     store.get("node0", "k")
     assert not made
-    # The same run with a tracer does make them, through the same class.
-    DServe(wf, n_nodes=2, cold_start=0.0, spans=Tracer()).run(
-        [0.0], {"request": np.arange(16.0)})
+    # The same run with a tracer does make them, through the same class;
+    # its first Puts digest nothing, and a co-write makes the digest spans.
+    traced = DServe(wf, n_nodes=2, cold_start=0.0, spans=Tracer())
+    traced.store.attach_tracer(None)    # DCheck's recorder digests
+    traced.run([0.0], {"request": np.arange(16.0)})
     assert {"admit", "request", "invoke", "slot", "exec", "get", "wait",
-            "put", "digest", "d2h"} <= set(made)
+            "put"} <= set(made)
+    assert not {"digest", "d2h"} & set(made)
+    store.attach_spans(Tracer())
+    store.put("node0", "k", {"a": np.ones(4)})
+    assert {"digest", "d2h"} <= set(made)
 
 
 # ----------------------------------------------------------------------

@@ -208,6 +208,36 @@ def test_fanout_workload_serves():
     assert all(s.outputs["response"] for s in rep.stats)
 
 
+class _DeviceFuture:
+    """A leaf whose value is ready 0.1 s after anyone starts waiting, as a
+    device array is once its producer's steps have been dispatched."""
+
+    def __init__(self):
+        self.ready = False
+
+    def block_until_ready(self):
+        time.sleep(0.1)
+        self.ready = True
+        return self
+
+
+def test_latency_ends_once_the_sink_outputs_are_ready():
+    """A Put publishes a device array before its producer has run, so a
+    request's latency runs until every such leaf of its sink outputs is
+    ready, not only until the last Put."""
+    def s0(request):
+        return {"response": {"head": request, "tail": [_DeviceFuture()]}}
+    wf = Workflow("async", [FunctionSpec("s0", ("request",), ("response",),
+                                         fn=s0, cold_start=0.0)])
+    srv = DServe(wf, n_nodes=1, cold_start=0.0, keepalive=10.0,
+                 get_timeout=10.0)
+    rep = srv.run([0.0], inputs={"request": b"q"})
+    assert rep.failures == 0
+    (stat,) = rep.stats
+    assert stat.outputs["response"]["tail"][0].ready
+    assert stat.latency >= 0.1
+
+
 # ----------------------------------------------------------------------
 # Failure injection across concurrent instances
 # ----------------------------------------------------------------------
