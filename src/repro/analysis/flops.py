@@ -29,6 +29,10 @@ def _expert_params(cfg: ModelConfig) -> int:
     return _mlp_params(cfg, cfg.d_ff)
 
 
+def _shared_params(cfg: ModelConfig) -> int:
+    return _mlp_params(cfg, cfg.shared_width) if cfg.n_shared_experts else 0
+
+
 def _mamba_params(cfg: ModelConfig) -> int:
     M, DI, N, H = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
     return (2 * M * DI          # w_z, w_x
@@ -46,7 +50,7 @@ def param_counts(cfg: ModelConfig) -> dict:
         layer = _attn_params(cfg) + _mlp_params(cfg, cfg.d_ff)
         total = active = L * layer
     elif fam == "moe":
-        shared = cfg.n_shared_experts * _mlp_params(cfg, cfg.d_ff)
+        shared = _shared_params(cfg)
         layer_fixed = _attn_params(cfg) + shared + M * cfg.n_experts
         total = L * (layer_fixed + cfg.n_experts * _expert_params(cfg))
         active = L * (layer_fixed + cfg.top_k * _expert_params(cfg))
@@ -58,10 +62,11 @@ def param_counts(cfg: ModelConfig) -> dict:
         n_moe = per // cfg.hybrid_moe_every
         n_mlp = per - n_moe
         mixers = _attn_params(cfg) + (per - 1) * _mamba_params(cfg)
-        ffn_total = (n_mlp * _mlp_params(cfg, cfg.d_ff)
-                     + n_moe * cfg.n_experts * _expert_params(cfg))
-        ffn_active = (n_mlp * _mlp_params(cfg, cfg.d_ff)
-                      + n_moe * cfg.top_k * _expert_params(cfg))
+        moe_fixed = _shared_params(cfg) + M * cfg.n_experts
+        ffn_total = (n_mlp * _mlp_params(cfg, cfg.d_ff) + n_moe
+                     * (moe_fixed + cfg.n_experts * _expert_params(cfg)))
+        ffn_active = (n_mlp * _mlp_params(cfg, cfg.d_ff) + n_moe
+                      * (moe_fixed + cfg.top_k * _expert_params(cfg)))
         total = nb * (mixers + ffn_total)
         active = nb * (mixers + ffn_active)
     elif fam == "encdec":

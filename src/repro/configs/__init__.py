@@ -1,6 +1,6 @@
 """Assigned-architecture registry: ``--arch <id>`` resolution.
 
-Ten architectures from the public pool (see each module's docstring for the
+Eleven architectures from the public pool (see each module's docstring for the
 source citation), plus the reduced variants used by CPU smoke tests.
 """
 
@@ -24,6 +24,7 @@ ARCHS = {
     "mamba2-370m": "mamba2_370m",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 

@@ -2,6 +2,11 @@
 
 72L d_model=8192 64H (GQA kv=8) d_ff=24576 vocab=65536, MoE 16e top-2,
 1 attention : 7 mamba interleave, MoE every other layer.
+
+Not the published model: the program's Mamba layers are Mamba-2 (SSD,
+d_state 128, 64-wide heads), where Jamba's are Mamba-1 with d_state 16 and
+a dt rank of its own (the catalog's AI21-Jamba2-Mini: ``mamba_d_state``
+16, ``mamba_dt_rank`` 256).
 """
 from repro.models.config import ModelConfig
 

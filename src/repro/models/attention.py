@@ -67,18 +67,20 @@ def attention_decls(cfg: ModelConfig, layers: int | None = None) -> dict:
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool, q_chunk: int, kv_chunk: int,
                         q_offset: int = 0,
-                        softmax_dtype=jnp.float32) -> jax.Array:
+                        softmax_dtype=jnp.float32,
+                        scale: float | None = None) -> jax.Array:
     """Online-softmax attention.
 
     q: (B, Sq, H, D);  k, v: (B, Skv, Hk, D) with H % Hk == 0.
     Returns (B, Sq, H, D).  ``q_offset`` shifts query positions for causal
     masking (prefill continuation).  ``softmax_dtype`` sets the materialized
-    score-pipeline dtype (running max/denominator stay fp32).
+    score-pipeline dtype (running max/denominator stay fp32).  ``scale``
+    multiplies the scores (default ``D ** -0.5``).
     """
     B, Sq, H, D = q.shape
     _, Skv, Hk, _ = k.shape
     G = H // Hk
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     q_chunk = min(q_chunk, Sq)
     kv_chunk = min(kv_chunk, Skv)
     if Sq % q_chunk or Skv % kv_chunk:
@@ -134,7 +136,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return outs.reshape(B, Sq, H, D)
 
 
-def decode_attention(q: jax.Array, cache: KVCache) -> jax.Array:
+def decode_attention(q: jax.Array, cache: KVCache,
+                     scale: float | None = None) -> jax.Array:
     """Single-step attention against a masked fixed-size cache.
 
     q: (B, 1, H, D); cache.k/v: (B, L, Hk, D).  Returns (B, 1, H, D).
@@ -142,7 +145,7 @@ def decode_attention(q: jax.Array, cache: KVCache) -> jax.Array:
     B, Sq, H, D = q.shape
     _, L, Hk, _ = cache.k.shape
     G = H // Hk
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     qr = q.reshape(B, Sq, Hk, G, D) * scale
     s = jnp.einsum("bqhgd,bkhd->bqhgk", qr, cache.k,
                    preferred_element_type=jnp.float32)
@@ -177,7 +180,8 @@ def attention(params: dict, x: jax.Array, cfg: ModelConfig, *,
       * cache given and S == 1           → cached decode step;
       * cache given and S > 1            → prefill that fills the cache.
     ``kv_source`` (encoder memory) switches to cross-attention (no rope,
-    no cache update, not causal).
+    no cache update, not causal).  ``cfg.use_rope`` False leaves positions
+    out (NoPE); ``cfg.attention_multiplier`` sets the softmax scale.
     Returns (out, new_cache_or_None).
     """
     B, S, M = x.shape
@@ -200,7 +204,8 @@ def attention(params: dict, x: jax.Array, cfg: ModelConfig, *,
         return jnp.repeat(t, G, axis=2)
 
     is_cross = kv_source is not None
-    if not is_cross:
+    scale = cfg.attention_multiplier
+    if not is_cross and cfg.use_rope:
         if positions is None:
             base = cache.length if cache is not None else 0
             positions = base + jnp.arange(S)[None, :]          # (1, S)
@@ -230,23 +235,23 @@ def attention(params: dict, x: jax.Array, cfg: ModelConfig, *,
         new_cache = KVCache(k_all, v_all, cache.length + S)
         if S == 1:
             if cfg.decode_unexpanded_gqa:
-                out = decode_attention(q, new_cache)
+                out = decode_attention(q, new_cache, scale)
             else:
                 out = decode_attention(
                     q, KVCache(expand_kv(k_all), expand_kv(v_all),
-                               new_cache.length))
+                               new_cache.length), scale)
         else:
             # Prefill: attend over the fresh tokens blockwise (cache assumed
             # empty before a prefill; continuation uses q_offset).
             out = blockwise_attention(
                 q, expand_kv(k), expand_kv(v), causal=causal,
                 q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-                softmax_dtype=jnp.dtype(cfg.softmax_dtype))
+                softmax_dtype=jnp.dtype(cfg.softmax_dtype), scale=scale)
     else:
         out = blockwise_attention(
             q, expand_kv(k), expand_kv(v), causal=causal and not is_cross,
             q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-            softmax_dtype=jnp.dtype(cfg.softmax_dtype))
+            softmax_dtype=jnp.dtype(cfg.softmax_dtype), scale=scale)
 
     if cfg.pad_heads_to:
         # Hard-mask the padded heads: output-exact w.r.t. the table config.

@@ -4,7 +4,9 @@
   * ``dense``   — decoder-only transformer (GQA + MLP)
   * ``moe``     — decoder-only with MoE FFN layers
   * ``ssm``     — Mamba-2 (SSD) stack, attention-free
-  * ``hybrid``  — Jamba-style 1:7 attn:mamba interleave with periodic MoE
+  * ``hybrid``  — Mamba-2 super-blocks with one attention sublayer and MoE
+                  on every ``hybrid_moe_every``-th FFN (Jamba: 1:7, every
+                  2nd; Granite-4.0-H: 1:9, every FFN)
   * ``encdec``  — encoder-decoder (seamless-m4t backbone)
   * ``vlm``     — decoder-only with M-RoPE + vision-embedding inputs (the
                   modality frontend is a stub: inputs are precomputed patch
@@ -36,17 +38,32 @@ class ModelConfig:
 
     # positional / norm flavor
     rope_theta: float = 10000.0
+    use_rope: bool = True                # False: no positions (NoPE)
     use_mrope: bool = False              # qwen2-vl
     qk_norm: bool = False                # qwen3
     activation: str = "silu"             # "silu" | "gelu" | "squared_relu"
     glu: bool = True                     # gated FFN (SwiGLU); False → plain
     tie_embeddings: bool = False
+    # Granite-style scalings: embeddings × embedding_multiplier, each
+    # sublayer's output × residual_multiplier before its residual add,
+    # logits ÷ logits_scaling; attention_multiplier is the softmax scale
+    # (None: head_dim ** -0.5).
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float | None = None
 
     # MoE
     n_experts: int = 0
     top_k: int = 0
     n_shared_experts: int = 0
+    shared_d_ff: int = 0                 # shared MLP width (0: d_ff × n)
     capacity_factor: float = 1.25
+    # expert parallelism: this device holds experts [first_expert,
+    # first_expert + experts_held) of every MoE layer (0: all n_experts);
+    # the router still covers all n_experts.
+    experts_held: int = 0
+    first_expert: int = 0
 
     # SSM (mamba2)
     ssm_state: int = 0
@@ -54,10 +71,10 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
 
-    # hybrid (jamba): layers per super-block and which are attention / MoE
+    # hybrid: layers per super-block and which are attention / MoE
     hybrid_period: int = 8
     hybrid_attn_index: int = 3           # 1 attn : 7 mamba
-    hybrid_moe_every: int = 2            # MoE on every 2nd sublayer
+    hybrid_moe_every: int = 2            # MoE on every 2nd sublayer (1: all)
 
     # encdec
     n_encoder_layers: int = 0
@@ -123,6 +140,15 @@ class ModelConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the shared-expert MLP beside the routed experts."""
+        return self.shared_d_ff or self.d_ff * self.n_shared_experts
+
+    @property
     def attends(self) -> bool:
         return self.family != "ssm"
 
@@ -134,7 +160,9 @@ class ModelConfig:
             self,
             name=self.name + "-reduced",
             n_layers=min(self.n_layers, 4) if self.family != "hybrid"
-                     else self.hybrid_period,
+                     else min(self.hybrid_period, 8),
+            hybrid_period=min(self.hybrid_period, 8),
+            hybrid_attn_index=min(self.hybrid_attn_index, 7),
             d_model=64,
             n_heads=heads,
             n_kv_heads=kv,
@@ -144,6 +172,9 @@ class ModelConfig:
             n_experts=min(self.n_experts, 8) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
             n_shared_experts=min(self.n_shared_experts, 1),
+            shared_d_ff=min(self.shared_d_ff, 256),
+            experts_held=0,
+            first_expert=0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=16,
             n_encoder_layers=min(self.n_encoder_layers, 2),

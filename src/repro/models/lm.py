@@ -9,7 +9,8 @@ activation sharding constraints at layer boundaries, and three entry points:
 * ``decode_step(params, cache, tok)`` — one token (the ``decode_*`` and
   ``long_500k`` dry-run cells lower this).
 
-Families: ``dense`` | ``moe`` | ``ssm`` (mamba-2) | ``hybrid`` (jamba) |
+Families: ``dense`` | ``moe`` | ``ssm`` (mamba-2) | ``hybrid`` (jamba,
+granite-4.0-h) |
 ``vlm`` (M-RoPE + precomputed patch embeddings — frontend stubbed per the
 assignment).
 """
@@ -94,7 +95,7 @@ class LM:
                                  init=ones_init),
                 "mamba": ssm_decls(cfg, layers=L),
             }
-        # hybrid (jamba): super-blocks of `period` sublayers
+        # hybrid: super-blocks of `period` sublayers
         nb = cfg.n_layers // cfg.hybrid_period
         per = cfg.hybrid_period
         n_mamba = per - 1
@@ -104,12 +105,13 @@ class LM:
             "mamba": ssm_decls(cfg, layers=n_mamba),
             "attn": attention_decls(cfg),
             "moe": moe_decls(cfg, layers=n_moe),
-            "mlp": mlp_decls(cfg, layers=n_mlp),
             "ln_mix": ArrayDecl((per, cfg.d_model), (None, "embed"),
                                 init=ones_init),
             "ln_ffn": ArrayDecl((per, cfg.d_model), (None, "embed"),
                                 init=ones_init),
         }
+        if n_mlp:
+            sub["mlp"] = mlp_decls(cfg, layers=n_mlp)
 
         def add_block_dim(d: ArrayDecl) -> ArrayDecl:
             return ArrayDecl((nb,) + d.shape, ("layers",) + d.axes,
@@ -134,63 +136,106 @@ class LM:
     # ------------------------------------------------------------------
     # layer bodies
     # ------------------------------------------------------------------
+    def _embed(self, params, tokens):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.bfloat16)
+        if self.cfg.embedding_multiplier != 1.0:
+            x = x * self.cfg.embedding_multiplier
+        return x
+
+    def _residual(self, x, h):
+        """``x + h``, the sublayer output ``h`` scaled by the residual
+        multiplier first."""
+        if self.cfg.residual_multiplier != 1.0:
+            h = h * self.cfg.residual_multiplier
+        return x + h
+
+    def _logits(self, params, x, *, last: bool = False):
+        """Logits of every position of ``x``, or of its last (``last``)."""
+        cfg = self.cfg
+        head = params["embed"].T if cfg.tie_embeddings else params["head"]
+        if last:
+            x = x[:, -1:]
+        logits = jnp.einsum("bsm,mv->bsv", x, head.astype(x.dtype))
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
+
     def _dense_layer(self, lp, x, *, mrope_positions=None, cache=None,
                      positions=None):
         cfg = self.cfg
         h, new_kv = attention(lp["attn"], rms_norm(x, lp["ln1"]), cfg,
                               mrope_positions=mrope_positions, cache=cache,
                               positions=positions)
-        x = x + h
+        x = self._residual(x, h)
         if "moe" in lp:
-            y, aux = moe(lp["moe"], rms_norm(x, lp["ln2"]), cfg)
+            y, aux = moe(lp["moe"], rms_norm(x, lp["ln2"]), cfg,
+                         dropless=cache is not None)
             # name the EP-psum result so the "names" remat policy can save
             # it — otherwise the backward re-executes the fwd psum (§Perf).
             from jax.ad_checkpoint import checkpoint_name
             y = checkpoint_name(y, "moe_out")
         else:
             y, aux = mlp(lp["mlp"], rms_norm(x, lp["ln2"]), cfg), 0.0
-        return x + y, aux, new_kv
+        return self._residual(x, y), aux, new_kv
 
     def _ssm_layer(self, lp, x, *, cache=None):
         h, new_ssm = mamba_block(lp["mamba"], rms_norm(x, lp["ln1"]),
                                  self.cfg, cache=cache)
-        return x + h, new_ssm
+        return self._residual(x, h), new_ssm
 
-    def _hybrid_block(self, bp, x, *, cache=None, positions=None):
-        """One jamba super-block: `period` sublayers, attn at one index,
-        MoE on alternating FFNs.  cache = (KVCache, SSMCache[n_mamba])."""
+    def _hybrid_block(self, bp, x, *, block=(), cache=None, positions=None):
+        """One super-block: `period` sublayers, attn at one index, Mamba-2
+        at the others; MoE on every ``hybrid_moe_every``-th FFN, a dense
+        MLP on the rest.  cache = (KVCache, SSMCache[n_mamba]).
+
+        ``bp`` and ``cache`` hold this block's parameters and cache, or
+        with ``block=(b,)`` every block's, stacked, of which block ``b`` is
+        run: each weight is then read with one index, and the routed
+        experts in place, so no block's weights are copied out (XLA
+        materializes a slice of a slice, and any operand of the grouped
+        matmul)."""
         cfg = self.cfg
         per = cfg.hybrid_period
+        every = cfg.hybrid_moe_every
+
+        def take(tree, *i):
+            return jax.tree.map(lambda a: a[block + i], tree)
         aux_total = 0.0
         mi = fi_moe = fi_mlp = 0
-        kv_in = cache.kv if cache is not None else None
-        ssm_in = cache.ssm if cache is not None else None
+        kv_in = take(cache.kv) if cache is not None else None
         kv_out, ssm_out = None, []
+        n_moe = per // every
+        moe_p = jax.tree.map(
+            lambda a: a.reshape((-1,) + a.shape[len(block) + 1:]), bp["moe"])
+        moe_base = block[0] * n_moe if block else 0
         for i in range(per):
-            xn = rms_norm(x, bp["ln_mix"][i])
-            if i == cfg.hybrid_attn_index:
-                h, kv_out = attention(bp["attn"], xn, cfg, cache=kv_in,
-                                      positions=positions)
-            else:
-                sc = jax.tree.map(lambda a: a[mi], ssm_in) \
-                    if ssm_in is not None else None
-                h, s_new = mamba_block(
-                    jax.tree.map(lambda a: a[mi], bp["mamba"]), xn, cfg,
-                    cache=sc)
-                if s_new is not None:
-                    ssm_out.append(s_new)
-                mi += 1
-            x = x + h
-            xn = rms_norm(x, bp["ln_ffn"][i])
-            if i % cfg.hybrid_moe_every == 1:
-                y, aux = moe(jax.tree.map(lambda a: a[fi_moe], bp["moe"]),
-                             xn, cfg)
+            xn = rms_norm(x, bp["ln_mix"][block + (i,)])
+            with jax.named_scope("mixer"):
+                if i == cfg.hybrid_attn_index:
+                    h, kv_out = attention(take(bp["attn"]), xn, cfg,
+                                          cache=kv_in, positions=positions)
+                else:
+                    sc = take(cache.ssm, mi) if cache is not None else None
+                    h, s_new = mamba_block(take(bp["mamba"], mi), xn, cfg,
+                                           cache=sc)
+                    if s_new is not None:
+                        # the new state's convolution tails are slices of
+                        # (B, S, d_inner) activations: take them now, or
+                        # XLA keeps every layer's activations alive until
+                        # the cache is stacked
+                        ssm_out.append(jax.lax.optimization_barrier(s_new))
+                    mi += 1
+            x = self._residual(x, h)
+            xn = rms_norm(x, bp["ln_ffn"][block + (i,)])
+            if i % every == every - 1:
+                y, aux = moe(moe_p, xn, cfg, dropless=cache is not None,
+                             layer=moe_base + fi_moe)
                 aux_total = aux_total + aux
                 fi_moe += 1
             else:
-                y = mlp(jax.tree.map(lambda a: a[fi_mlp], bp["mlp"]), xn, cfg)
+                y = mlp(take(bp["mlp"], fi_mlp), xn, cfg)
                 fi_mlp += 1
-            x = x + y
+            x = self._residual(x, y)
         new_cache = None
         if cache is not None:
             new_cache = Cache(
@@ -206,7 +251,7 @@ class LM:
                 mrope_positions=None):
         """tokens: (B, S) → logits (B, S, V); also returns aux loss."""
         cfg = self.cfg
-        x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.bfloat16)
+        x = self._embed(params, tokens)
         if vision_embeds is not None:
             nv = vision_embeds.shape[1]
             x = jnp.concatenate(
@@ -244,9 +289,7 @@ class LM:
                 body = jax.checkpoint(body)
         (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), lp)
         x = rms_norm(x, params["final_norm"])
-        head = params["embed"].T if cfg.tie_embeddings else params["head"]
-        logits = jnp.einsum("bsm,mv->bsv", x, head.astype(x.dtype))
-        return logits, aux
+        return self._logits(params, x), aux
 
     def loss_fn(self, params, batch):
         """batch: {'tokens': (B, S+1), optional 'vision_embeds',
@@ -290,8 +333,7 @@ class LM:
                       mrope_positions=None):
         cfg = self.cfg
         fam = cfg.family
-        x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.bfloat16)
-        x = _constrain_tokens(x, cfg)
+        x = _constrain_tokens(self._embed(params, tokens), cfg)
 
         def body(carry, inp):
             x = carry
@@ -301,20 +343,25 @@ class LM:
                     layer_params, x, cache=layer_cache.kv,
                     mrope_positions=mrope_positions)
                 new_cache = Cache(kv=new_kv)
-            elif fam == "ssm":
+            else:
                 x2, new_ssm = self._ssm_layer(layer_params, x,
                                               cache=layer_cache.ssm)
                 new_cache = Cache(ssm=new_ssm)
-            else:
-                x2, _, new_cache = self._hybrid_block(layer_params, x,
-                                                      cache=layer_cache)
             return x2, new_cache
 
-        x, new_caches = jax.lax.scan(body, x, (params["layers"], cache))
+        if fam == "hybrid":
+            # unrolled over the blocks, each reading the stacked weights
+            # in place (see _hybrid_block)
+            blocks = []
+            for b in range(cfg.n_layers // cfg.hybrid_period):
+                x, _, c = self._hybrid_block(params["layers"], x, block=(b,),
+                                             cache=cache)
+                blocks.append(c)
+            new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
+        else:
+            x, new_caches = jax.lax.scan(body, x, (params["layers"], cache))
         x = rms_norm(x, params["final_norm"])
-        head = params["embed"].T if cfg.tie_embeddings else params["head"]
-        logits = jnp.einsum("bsm,mv->bsv", x[:, -1:], head.astype(x.dtype))
-        return logits, new_caches
+        return self._logits(params, x, last=True), new_caches
 
     def prefill(self, params, tokens, cache: Cache, **kw):
         """tokens: (B, S).  Returns (last-token logits, filled cache)."""

@@ -14,6 +14,16 @@ one-hot of the classic GShard formulation — with 384-expert configs that
 tensor would be ~100 GB.  Token overflow beyond the per-expert capacity
 ``C = ceil(T·k/E · capacity_factor)`` is dropped (standard GShard dropping
 semantics); a load-balance auxiliary loss keeps the router honest.
+
+Serving drops nothing (``dropless``): the (token, expert) pairs are sorted
+by expert and the experts run as one grouped matmul over the sorted rows
+(``jax.lax.ragged_dot``), whose groups are as long as the routing makes
+them.
+
+A device may hold a share of the experts (``cfg.experts_held`` from
+``cfg.first_expert``, then split over the ``model`` axis): the router still
+covers all ``n_experts`` and picks ``top_k`` of them, and the layer returns
+only the part of the result its own experts give, plus the shared MLP.
 """
 
 from __future__ import annotations
@@ -35,25 +45,29 @@ __all__ = ["moe_decls", "moe"]
 
 def moe_decls(cfg: ModelConfig, layers: int | None = None) -> dict:
     M, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    Eh = cfg.n_experts_held
     lead = (layers,) if layers else ()
     lax_ = ("layers",) if layers else ()
     decls = {
         "router": ArrayDecl(lead + (M, E), lax_ + ("embed", None),
                             init=normal_init(0.02), dtype=jnp.float32),
-        "w_up": ArrayDecl(lead + (E, M, F),
+        "w_up": ArrayDecl(lead + (Eh, M, F),
                           lax_ + ("experts", "embed", "expert_mlp")),
-        "w_down": ArrayDecl(lead + (E, F, M),
+        "w_down": ArrayDecl(lead + (Eh, F, M),
                             lax_ + ("experts", "expert_mlp", "embed")),
     }
     if cfg.glu:
-        decls["w_gate"] = ArrayDecl(lead + (E, M, F),
+        decls["w_gate"] = ArrayDecl(lead + (Eh, M, F),
                                     lax_ + ("experts", "embed", "expert_mlp"))
     if cfg.n_shared_experts:
-        Fs = F * cfg.n_shared_experts
+        Fs = cfg.shared_width
         decls["shared_up"] = ArrayDecl(lead + (M, Fs), lax_ + ("embed", "mlp"))
         decls["shared_gate"] = ArrayDecl(lead + (M, Fs), lax_ + ("embed", "mlp"))
         decls["shared_down"] = ArrayDecl(lead + (Fs, M), lax_ + ("mlp", "embed"))
     return decls
+
+
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
 def _capacity(tokens: int, k: int, n_experts: int, factor: float) -> int:
@@ -61,39 +75,29 @@ def _capacity(tokens: int, k: int, n_experts: int, factor: float) -> int:
     return max(c, 4)
 
 
-def _moe_local(x, topi, gates, w_gate, w_up, w_down, shared, *,
-               cfg: ModelConfig, n_model: int, has_model_axis: bool,
-               d_axes: tuple[str, ...] = ()):
-    """Per-device block: x (B_loc, S, M); experts (E_loc, ...).
-
-    Routing (``topi``/``gates``, (B_loc, S, k)) is computed *outside* the
-    shard_map in global pjit land — computing it per-rank would make every
-    routing intermediate a replicated value whose cotangent needs a psum
-    over the model axis (measured: ~2 extra activation-sized all-reduces
-    per layer, §Perf kimi iteration 2)."""
-    B, S, M = x.shape
-    E, k = cfg.n_experts, cfg.top_k
-    E_loc = E // n_model
-    act = ACTIVATIONS[cfg.activation]
-    t = x.reshape(B * S, M)
-    T = B * S
-    topi = topi.reshape(T, k)
-    gates = gates.reshape(T, k)
-    # Capacity per (local) expert: expected load is T·k/E tokens from this
-    # data shard's block, padded by the capacity factor.
-    C = _capacity(T, k, E, cfg.capacity_factor)
-
-    rank = jax.lax.axis_index("model") if has_model_axis else 0
-    e_base = rank * E_loc
-    local = topi - e_base                                      # (T, k)
-    sel = (local >= 0) & (local < E_loc)
-    lid = jnp.where(sel, local, E_loc)                         # E_loc = drop
-    lid_f = lid.reshape(-1)                                    # (T*k,)
-
-    # rank within expert (stable sort → arrival-order priority on overflow)
+def _sort_pairs(lid_f, E_loc: int):
+    """Order of the (token, slot) pairs by local expert (stable: arrival
+    order within an expert), the sorted experts, and where each expert's
+    rows begin, (E_loc + 1,)."""
     order = jnp.argsort(lid_f, stable=True)
     sorted_lid = lid_f[order]
     starts = jnp.searchsorted(sorted_lid, jnp.arange(E_loc + 1))
+    return order, sorted_lid, starts
+
+
+def _experts_capacity(t, lid_f, gates, w_gate, w_up, w_down, act, *,
+                      cfg: ModelConfig, k: int):
+    """The held experts over an ``(E_loc, C, M)`` buffer of at most ``C``
+    tokens each (``C = ceil(T·k/E · capacity_factor)``); the overflow is
+    dropped.  Same arguments as :func:`_experts_dropless`; returns the
+    gated sum (T, M) in fp32."""
+    T, M = t.shape
+    E_loc = w_up.shape[0]
+    # Capacity per (local) expert: expected load is T·k/E tokens from this
+    # data shard's block, padded by the capacity factor.
+    C = _capacity(T, k, cfg.n_experts, cfg.capacity_factor)
+    order, sorted_lid, starts = _sort_pairs(lid_f, E_loc)
+    # rank within expert (stable sort → arrival-order priority on overflow)
     pos_sorted = jnp.arange(T * k) - starts[sorted_lid]
 
     if cfg.moe_dispatch == "gather":
@@ -136,28 +140,112 @@ def _moe_local(x, topi, gates, w_gate, w_up, w_down, shared, *,
         gate_grid = jnp.where(vgrid, gates.reshape(-1)[grid], 0.0)
         contrib = (out_buf.astype(jnp.float32)
                    * gate_grid[..., None].astype(jnp.float32))
-        y = jnp.zeros((T, M), jnp.float32).at[tok_grid.reshape(-1)].add(
+        return jnp.zeros((T, M), jnp.float32).at[tok_grid.reshape(-1)].add(
             contrib.reshape(-1, M))
+    out_buf = jnp.concatenate(
+        [out_buf, jnp.zeros((E_loc, 1, M), out_buf.dtype)], axis=1)
+    y_tk = out_buf[eid, slot] * keep[:, None]              # (T*k, M)
+    w = (gates.reshape(-1) * keep).astype(jnp.float32)
+    return (y_tk.astype(jnp.float32) * w[:, None]).reshape(T, k, M).sum(1)
+
+
+def _experts_dropless(t, lid_f, gates, w_gate, w_up, w_down, act, *,
+                      k: int, layer=None):
+    """Every (token, held expert) pair through its expert, as one grouped
+    matmul over the pairs sorted by expert: nothing is dropped.  t: (T, M);
+    ``lid_f`` (T·k,) the local expert of each (token, slot) pair, E_loc for
+    one held elsewhere; ``gates`` (T, k).  With ``layer``, the expert
+    weights are stacked over layers, (L, E_loc, ...), and the matmul takes
+    the whole stack with empty groups for the other layers' experts.
+    Returns the gated sum (T, M) in fp32."""
+    T, M = t.shape
+    E_loc = w_up.shape[-3]
+    order, sorted_lid, starts = _sort_pairs(lid_f, E_loc)
+    sizes = (starts[1:] - starts[:-1]).astype(jnp.int32)   # (E_loc,)
+    if layer is not None:
+        w_gate, w_up, w_down = (None if w is None else
+                                w.reshape((-1,) + w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((w_up.shape[0],), jnp.int32), sizes, (layer * E_loc,))
+    rows = t[order // k]                                   # (T*k, M)
+    up = jax.lax.ragged_dot(rows, w_up, sizes)
+    if w_gate is not None:
+        h = act(jax.lax.ragged_dot(rows, w_gate, sizes)) * up
     else:
-        out_buf = jnp.concatenate(
-            [out_buf, jnp.zeros((E_loc, 1, M), out_buf.dtype)], axis=1)
-        y_tk = out_buf[eid, slot] * keep[:, None]          # (T*k, M)
-        w = (gates.reshape(-1) * keep).astype(jnp.float32)
-        y = (y_tk.astype(jnp.float32) * w[:, None]).reshape(T, k, M).sum(1)
+        h = act(up)
+    out = jax.lax.ragged_dot(h, w_down, sizes)             # (T*k, M)
+    out = jnp.where((sorted_lid < E_loc)[:, None], out, 0)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+    inv = inv.reshape(T, k)
+    gates = gates.astype(jnp.float32)
+    y = jnp.zeros((T, M), jnp.float32)
+    for j in range(k):
+        # one (T, M) gather a slot, fused into the gated add: no
+        # (T, k, M) fp32 tensor
+        y = y + out[inv[:, j]].astype(jnp.float32) * gates[:, j, None]
+    return y
+
+
+def _moe_local(x, topi, gates, w_gate, w_up, w_down, shared, *,
+               cfg: ModelConfig, n_model: int, has_model_axis: bool,
+               d_axes: tuple[str, ...] = (), dropless: bool = False,
+               layer=None):
+    """Per-device block: x (B_loc, S, M); experts (E_loc, ...).
+
+    Routing (``topi``/``gates``, (B_loc, S, k)) is computed *outside* the
+    shard_map in global pjit land — computing it per-rank would make every
+    routing intermediate a replicated value whose cotangent needs a psum
+    over the model axis (measured: ~2 extra activation-sized all-reduces
+    per layer, §Perf kimi iteration 2)."""
+    B, S, M = x.shape
+    k = cfg.top_k
+    E_loc = cfg.n_experts_held // n_model
+    act = ACTIVATIONS[cfg.activation]
+    t = x.reshape(B * S, M)
+    T = B * S
+    topi = topi.reshape(T, k)
+    gates = gates.reshape(T, k)
+
+    with jax.named_scope("routed_experts"):
+        rank = jax.lax.axis_index("model") if has_model_axis else 0
+        e_base = cfg.first_expert + rank * E_loc
+        local = topi - e_base                                  # (T, k)
+        sel = (local >= 0) & (local < E_loc)
+        lid = jnp.where(sel, local, E_loc)                     # E_loc = drop
+        lid_f = lid.reshape(-1)                                # (T*k,)
+        if dropless:
+            y = _experts_dropless(t, lid_f, gates, w_gate, w_up, w_down, act,
+                                  k=k, layer=layer)
+        else:
+            if layer is not None:
+                w_gate, w_up, w_down = (None if w is None else w[layer]
+                                        for w in (w_gate, w_up, w_down))
+            y = _experts_capacity(t, lid_f, gates, w_gate, w_up, w_down, act,
+                                  cfg=cfg, k=k)
 
     if shared is not None:
-        s_gate, s_up, s_down = shared
-        g = t @ s_gate
-        u = t @ s_up
-        y = y + ((act(g) * u) @ s_down).astype(jnp.float32)
+        with jax.named_scope("shared_mlp"):
+            s_gate, s_up, s_down = shared
+            g = t @ s_gate
+            u = t @ s_up
+            y = y + ((act(g) * u) @ s_down).astype(jnp.float32)
 
     if has_model_axis:
         y = jax.lax.psum(y, "model")
     return y.reshape(B, S, M).astype(x.dtype)
 
 
-def moe(params: dict, x: jax.Array, cfg: ModelConfig):
-    """MoE sublayer.  x: (B, S, M) → (y, aux_loss)."""
+def moe(params: dict, x: jax.Array, cfg: ModelConfig, *,
+        dropless: bool = False, layer=None):
+    """MoE sublayer.  x: (B, S, M) → (y, aux_loss).  ``dropless`` (serving)
+    runs every routed token through its expert, whatever the load.  With
+    ``layer``, every leaf of ``params`` carries a leading axis of layers and
+    this is the layer's index into it: the dropless path then reads the
+    routed experts in place, not from a copy of the layer's slice."""
+    if layer is not None:
+        params = {n: v if n in _EXPERT_LEAVES else v[layer]
+                  for n, v in params.items()}
     mesh = current_mesh()
     m_axis = model_axis(mesh)
     d_axes = data_axes(mesh)
@@ -167,16 +255,17 @@ def moe(params: dict, x: jax.Array, cfg: ModelConfig):
 
     # -- routing in global pjit land (replicated math stays out of the
     # manual region; see _moe_local docstring) --------------------------
-    logits = jnp.einsum("bsm,me->bse", x.astype(jnp.float32),
-                        params["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(probs, k)                # (B, S, k)
-    gates = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-    # GShard load-balance aux: importance × top-1 load over global tokens.
-    me = probs.reshape(-1, E).mean(axis=0)
-    ce = jax.nn.one_hot(topi[..., 0].reshape(-1), E,
-                        dtype=jnp.float32).mean(axis=0)
-    aux = E * jnp.sum(me * ce)
+    with jax.named_scope("router"):
+        logits = jnp.einsum("bsm,me->bse", x.astype(jnp.float32),
+                            params["router"])
+        probs = jax.nn.softmax(logits, axis=-1)
+        topv, topi = jax.lax.top_k(probs, k)            # (B, S, k)
+        gates = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+        # GShard load-balance aux: importance × top-1 load over tokens.
+        me = probs.reshape(-1, E).mean(axis=0)
+        ce = jax.nn.one_hot(topi[..., 0].reshape(-1), E,
+                            dtype=jnp.float32).mean(axis=0)
+        aux = E * jnp.sum(me * ce)
 
     shared = None
     if cfg.n_shared_experts:
@@ -184,7 +273,8 @@ def moe(params: dict, x: jax.Array, cfg: ModelConfig):
                   params["shared_down"])
 
     fn = partial(_moe_local, cfg=cfg, n_model=n_model,
-                 has_model_axis=has_model, d_axes=d_axes)
+                 has_model_axis=has_model, d_axes=d_axes, dropless=dropless,
+                 layer=layer)
 
     nd = 1
     for a in d_axes:
@@ -195,7 +285,8 @@ def moe(params: dict, x: jax.Array, cfg: ModelConfig):
         bspec = None        # tiny decode batches: replicate tokens
     dspec = P(bspec, None, None)                        # (B, S, M)
     kspec = P(bspec, None, None)                        # (B, S, k)
-    espec3 = P(m_axis, None, None)                      # (E, M, F)
+    espec3 = P(m_axis, None, None) if layer is None \
+        else P(None, m_axis, None, None)                # ([L,] E, M, F)
     sspec = P(None, m_axis)                             # shared up/gate (M,Fs)
     sdspec = P(m_axis, None)                            # shared down (Fs,M)
 

@@ -116,7 +116,7 @@ def test_model_flops_train_vs_prefill():
 # -------------------------------------------------------------- cells
 def test_live_cells_and_skips():
     cells = live_cells()
-    assert len(cells) == 32                      # 10*3 + 2 long_500k
+    assert len(cells) == 35                      # 11*3 + 2 long_500k
     assert is_skipped("starcoder2-15b", "long_500k")
     assert not is_skipped("mamba2-370m", "long_500k")
     assert not is_skipped("jamba-1.5-large-398b", "long_500k")

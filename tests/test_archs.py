@@ -30,7 +30,7 @@ def _batch(cfg, key=2, seq=S):
 
 
 def test_all_archs_registered():
-    assert len(list_archs()) == 10
+    assert len(list_archs()) == 11
 
 
 @pytest.mark.parametrize("arch", list_archs())
@@ -48,6 +48,7 @@ def test_arch_full_config_matches_table(arch):
         "mamba2-370m": (48, 1024, 0, 0, 0, 50280),
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
         "seamless-m4t-large-v2": (24, 1024, 16, 16, 8192, 256206),
+        "granite-4.0-h-small": (40, 4096, 32, 8, 768, 100352),
     }
     L, d, h, kv, ff, v = table[arch]
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
@@ -61,6 +62,11 @@ def test_arch_full_config_matches_table(arch):
         assert cfg.hybrid_period == 8          # 1 attn : 7 mamba
     if arch == "mamba2-370m":
         assert cfg.ssm_state == 128
+    if arch == "granite-4.0-h-small":
+        assert (cfg.n_experts, cfg.top_k, cfg.shared_width) == (72, 10, 1536)
+        assert (cfg.hybrid_period, cfg.hybrid_attn_index,
+                cfg.hybrid_moe_every) == (10, 5, 1)   # 1 attn : 9 mamba
+        assert not cfg.use_rope and cfg.attention_multiplier == 1 / 128
 
 
 @pytest.mark.parametrize("arch", list_archs())
