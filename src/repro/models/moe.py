@@ -16,9 +16,11 @@ tensor would be ~100 GB.  Token overflow beyond the per-expert capacity
 semantics); a load-balance auxiliary loss keeps the router honest.
 
 Serving drops nothing (``dropless``): the (token, expert) pairs are sorted
-by expert and the experts run as one grouped matmul over the sorted rows
-(``jax.lax.ragged_dot``), whose groups are as long as the routing makes
-them.
+by expert; where they outnumber the experts held (prefill) the experts run
+as one grouped matmul over the sorted rows (``jax.lax.ragged_dot``), whose
+groups are as long as the routing makes them, and else (decode) as a loop
+over the held experts that have rows, so a step reads no other expert's
+weights.
 
 A device may hold a share of the experts (``cfg.experts_held`` from
 ``cfg.first_expert``, then split over the ``model`` axis): the router still
@@ -149,25 +151,30 @@ def _experts_capacity(t, lid_f, gates, w_gate, w_up, w_down, act, *,
     return (y_tk.astype(jnp.float32) * w[:, None]).reshape(T, k, M).sum(1)
 
 
-def _experts_dropless(t, lid_f, gates, w_gate, w_up, w_down, act, *,
-                      k: int, layer=None):
-    """Every (token, held expert) pair through its expert, as one grouped
-    matmul over the pairs sorted by expert: nothing is dropped.  t: (T, M);
-    ``lid_f`` (T·k,) the local expert of each (token, slot) pair, E_loc for
-    one held elsewhere; ``gates`` (T, k).  With ``layer``, the expert
-    weights are stacked over layers, (L, E_loc, ...), and the matmul takes
-    the whole stack with empty groups for the other layers' experts.
-    Returns the gated sum (T, M) in fp32."""
-    T, M = t.shape
-    E_loc = w_up.shape[-3]
-    order, sorted_lid, starts = _sort_pairs(lid_f, E_loc)
+def _in_place(w_gate, w_up, w_down, *, layer, held: int):
+    """The expert leaves as one axis of groups and the first group of this
+    layer's experts: leaves stacked over layers, (L, held, ...), are read
+    in place as (L·held, ...), never as a copy of the layer's slice."""
+    if layer is None:
+        return (w_gate, w_up, w_down), 0
+    return tuple(None if w is None else w.reshape((-1,) + w.shape[2:])
+                 for w in (w_gate, w_up, w_down)), layer * held
+
+
+def _experts_ragged(t, order, sorted_lid, starts, w_gate, w_up, w_down, act,
+                    *, k: int, layer=None):
+    """The pairs, sorted by expert, through their experts as one grouped
+    matmul a projection (``jax.lax.ragged_dot``) over every group of the
+    leaves, empty for the other layers' experts.  Serves prefill, where
+    the pairs outnumber the experts held.  Returns (T·k, M) in sorted
+    order, 0 for a pair held elsewhere."""
+    E_loc = starts.shape[0] - 1
     sizes = (starts[1:] - starts[:-1]).astype(jnp.int32)   # (E_loc,)
+    (w_gate, w_up, w_down), base = _in_place(w_gate, w_up, w_down,
+                                             layer=layer, held=E_loc)
     if layer is not None:
-        w_gate, w_up, w_down = (None if w is None else
-                                w.reshape((-1,) + w.shape[2:])
-                                for w in (w_gate, w_up, w_down))
         sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((w_up.shape[0],), jnp.int32), sizes, (layer * E_loc,))
+            jnp.zeros((w_up.shape[0],), jnp.int32), sizes, (base,))
     rows = t[order // k]                                   # (T*k, M)
     up = jax.lax.ragged_dot(rows, w_up, sizes)
     if w_gate is not None:
@@ -175,7 +182,65 @@ def _experts_dropless(t, lid_f, gates, w_gate, w_up, w_down, act, *,
     else:
         h = act(up)
     out = jax.lax.ragged_dot(h, w_down, sizes)             # (T*k, M)
-    out = jnp.where((sorted_lid < E_loc)[:, None], out, 0)
+    return jnp.where((sorted_lid < E_loc)[:, None], out, 0)
+
+
+def _experts_hit(t, order, sorted_lid, starts, w_gate, w_up, w_down, act,
+                 *, k: int, layer=None):
+    """The same, one held expert that has rows at a time: a loop of as
+    many trips as such experts (0 to ``min(T·k, E_loc)``), each reading
+    its expert's three matrices by a dynamic index into the leaves and
+    applying them to every row, of which it keeps its expert's.  Serves
+    decode, where the experts held outnumber the pairs: the weight traffic
+    is the hit experts' alone, where a grouped matmul steps through every
+    group of the stack."""
+    E_loc = starts.shape[0] - 1
+    (w_gate, w_up, w_down), base = _in_place(w_gate, w_up, w_down,
+                                             layer=layer, held=E_loc)
+    rows = t[order // k]                                   # (T*k, M)
+    first = jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                             sorted_lid[1:] != sorted_lid[:-1]])
+    first = first & (sorted_lid < E_loc)
+    hit = sorted_lid[jnp.nonzero(first, size=rows.shape[0])[0]]
+
+    def dot(x, leaf, e):
+        w = jax.lax.dynamic_index_in_dim(leaf, base + e, keepdims=False)
+        return jnp.dot(x, w, preferred_element_type=jnp.float32
+                       ).astype(rows.dtype)
+
+    def trip(i, out):
+        e = hit[i]
+        up = dot(rows, w_up, e)
+        if w_gate is not None:
+            h = act(dot(rows, w_gate, e)) * up
+        else:
+            h = act(up)
+        return jnp.where((sorted_lid == e)[:, None], dot(h, w_down, e), out)
+
+    with jax.named_scope("routed_experts_hit"):
+        return jax.lax.fori_loop(0, first.sum(), trip, jnp.zeros_like(rows))
+
+
+def _experts_dropless(t, lid_f, gates, w_gate, w_up, w_down, act, *,
+                      k: int, layer=None):
+    """Every (token, held expert) pair through its expert: nothing is
+    dropped.  t: (T, M); ``lid_f`` (T·k,) the local expert of each (token,
+    slot) pair, E_loc for one held elsewhere; ``gates`` (T, k).  With
+    ``layer``, the expert weights are stacked over layers, (L, E_loc, ...),
+    and are read in place.
+
+    The pairs are sorted by expert, then go one of two ways, chosen by
+    shape: where they outnumber the experts held (prefill),
+    :func:`_experts_ragged`, a grouped matmul a projection over the whole
+    stack (the other layers' experts as empty groups); else (decode,
+    ``T·k <= E_loc``) :func:`_experts_hit`, a loop over the held experts
+    that have rows.  Returns the gated sum (T, M) in fp32."""
+    T, M = t.shape
+    E_loc = w_up.shape[-3]
+    order, sorted_lid, starts = _sort_pairs(lid_f, E_loc)
+    experts = _experts_hit if T * k <= E_loc else _experts_ragged
+    out = experts(t, order, sorted_lid, starts, w_gate, w_up, w_down, act,
+                  k=k, layer=layer)                        # (T*k, M)
     inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
     inv = inv.reshape(T, k)
     gates = gates.astype(jnp.float32)
@@ -239,10 +304,15 @@ def _moe_local(x, topi, gates, w_gate, w_up, w_down, shared, *,
 def moe(params: dict, x: jax.Array, cfg: ModelConfig, *,
         dropless: bool = False, layer=None):
     """MoE sublayer.  x: (B, S, M) → (y, aux_loss).  ``dropless`` (serving)
-    runs every routed token through its expert, whatever the load.  With
-    ``layer``, every leaf of ``params`` carries a leading axis of layers and
-    this is the layer's index into it: the dropless path then reads the
-    routed experts in place, not from a copy of the layer's slice."""
+    runs every routed token through its expert, whatever the load, by one
+    of two paths chosen by shape (:func:`_experts_dropless`): a grouped
+    matmul over every held expert where the (token, slot) pairs outnumber
+    the experts held (prefill), a loop over the held experts that have
+    rows where they do not (decode).  With ``layer``, every leaf of
+    ``params`` carries a leading axis of layers and this is the layer's
+    index into it: both paths then read the routed experts in place, not
+    from a copy of the layer's slice; the grouped matmul, over every layer's
+    groups, serves prefill alone."""
     if layer is not None:
         params = {n: v if n in _EXPERT_LEAVES else v[layer]
                   for n, v in params.items()}

@@ -83,6 +83,26 @@ def test_prefill_then_decode_matches_reference(seed):
     assert err.max() < 0.03
 
 
+def test_decode_step_loops_over_hit_experts_prefill_keeps_ragged_dot():
+    """Batch-1 decode (T·k = 2 pairs, 4 experts held) runs the routed
+    experts as a loop over the held experts a token picked; a 48-token
+    prefill (96 pairs) keeps the grouped matmul."""
+    model = build_model(small_config())
+    params = jax.eval_shape(
+        lambda: init_params(model.param_decls(), jax.random.key(0)))
+    cache = model.init_cache(1, 56)
+
+    def step_text(step, n):
+        return str(jax.make_jaxpr(step)(
+            params, jax.ShapeDtypeStruct((1, n), jnp.int32), cache))
+    decode, prefill = step_text(model.decode_step, 1), \
+        step_text(model.prefill, 48)
+    assert "ragged_dot_general" not in decode
+    # one loop over the hit experts a layer
+    assert decode.count("while[") == 10
+    assert prefill.count("ragged_dot_general") == 3 * 10
+
+
 def _moe_inputs(seed=0, T=24):
     cfg = small_config(experts_held=0, first_expert=0)
     params = init_params(moe_decls(cfg), jax.random.key(seed))
