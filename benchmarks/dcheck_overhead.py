@@ -1,15 +1,17 @@
 """DCheck overhead: serve_load smoke with the trace checker on vs off.
 
-The recorder hook is designed to be zero-cost when detached (one ``is
-None`` test per instrumentation point) and cheap when attached (an
-append + digest under one lock).  This benchmark pins both claims to a
+The recorder rides on the data plane's one hook, the DScope
+:class:`Tracer`: zero-cost when detached (one ``is None`` test per
+instrumentation point) and cheap when attached (a span per data-plane
+operation, plus an event append + digest under one lock).  This benchmark pins both claims to a
 number and writes ``BENCH_dcheck.json`` so later PRs (sharded DStore,
 dynamic DAGs) can see whether they regressed the checker's overhead.
 
 Methodology: the serve_load SMOKE configuration (one rate, 10 Poisson
 arrivals of the 4-stage Srv chain) runs once with no tracer, once with a
-:class:`TraceRecorder` attached (no stress sleeps — those measure the
-*scheduler*, not the checker), and the p50/p99/wall numbers are compared.
+``Tracer(recorder=TraceRecorder())`` attached (no stress sleeps — those
+measure the *scheduler*, not the checker), and the p50/p99/wall numbers
+are compared.
 The traced run's events are then replayed through :class:`TraceChecker`
 and its offline check time is reported separately — the checker never
 sits on the serving path.
@@ -22,18 +24,18 @@ import json
 import time
 
 from repro.core.check import TraceChecker, TraceRecorder
-from repro.core.obs import bench_doc, bench_metric
+from repro.core.obs import Tracer, bench_doc, bench_metric
 from repro.core.serve import DServe, poisson_arrivals
 from repro.core.workloads import serving_chain
 
 SMOKE = dict(rate=8.0, n=10, stages=4, exec_time=0.03, cold_start=0.15)
 
 
-def _run_once(tracer, *, rate, n, stages, exec_time, cold_start):
+def _run_once(spans, *, rate, n, stages, exec_time, cold_start):
     wf = serving_chain(stages=stages, exec_time=exec_time,
                        cold_start=cold_start, payload=16 * 1024)
     srv = DServe(wf, n_nodes=2, pattern="dataflow", keepalive=10.0,
-                 max_per_node=16, tracer=tracer)
+                 max_per_node=16, spans=spans)
     rep = srv.run(poisson_arrivals(rate, n, seed=7),
                   inputs={"request": b"req"})
     assert rep.failures == 0, "instances failed during benchmark"
@@ -50,7 +52,7 @@ def measure(cfg=SMOKE, repeats: int = 3):
     def traced():
         rec = TraceRecorder()
         recorders.append(rec)
-        return _run_once(rec, **cfg)
+        return _run_once(Tracer(recorder=rec), **cfg)
 
     on = min((traced() for _ in range(repeats)),
              key=lambda r: r.wall_time)

@@ -27,7 +27,7 @@ import json
 import time
 
 from repro.core.check import PlanConformance, TraceRecorder
-from repro.core.obs import bench_doc, bench_metric
+from repro.core.obs import Tracer, bench_doc, bench_metric
 from repro.core.partition import partition_workflow
 from repro.core.plan import build_plan
 from repro.core.serve import DServe, poisson_arrivals
@@ -55,11 +55,11 @@ def plan_build_times(repeats: int = 5):
     return out
 
 
-def _run_once(*, plan, tracer=None, rate, n, stages, exec_time, cold_start):
+def _run_once(*, plan, spans=None, rate, n, stages, exec_time, cold_start):
     wf = serving_chain(stages=stages, exec_time=exec_time,
                        cold_start=cold_start, payload=16 * 1024)
     srv = DServe(wf, n_nodes=2, pattern="dataflow", keepalive=10.0,
-                 max_per_node=16, plan=plan, tracer=tracer)
+                 max_per_node=16, plan=plan, spans=spans)
     rep = srv.run(poisson_arrivals(rate, n, seed=7),
                   inputs={"request": b"req"})
     assert rep.failures == 0, "instances failed during benchmark"
@@ -74,7 +74,7 @@ def measure(cfg=SMOKE, repeats: int = 3):
 
     # One traced plan-driven run, conformance-checked end to end.
     rec = TraceRecorder()
-    traced, srv = _run_once(plan=True, tracer=rec, **cfg)
+    traced, srv = _run_once(plan=True, spans=Tracer(recorder=rec), **cfg)
     violations = PlanConformance(srv.plan).check(
         rec.events(), instances=[s.instance for s in traced.stats])
     assert not violations, [str(v) for v in violations]

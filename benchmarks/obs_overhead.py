@@ -1,11 +1,11 @@
 """DScope overhead: serve_load smoke with full observability on vs off.
 
-DScope's hooks follow the DCheck recorder discipline: one ``is None``
-test per instrumentation point when detached.  The *off* arm is the
-production default — DServe's internal :class:`MetricsRegistry` with
-pull-only collectors (scraped once per run, zero hot-path work).  The
-*on* arm attaches everything at once: an explicit registry (arming the
-push histograms on the Get/stream/latency paths) plus a
+DScope's one hook, the :class:`Tracer`, costs one ``is None`` test per
+instrumentation point when detached.  The *off* arm is the production
+default — DServe's internal :class:`MetricsRegistry` with pull-only
+collectors (scraped once per run, zero hot-path work).  The *on* arm
+attaches everything at once: an explicit registry (which DServe fills
+with its serving-latency histograms after the run) plus a
 :class:`Tracer` recording the full request → invoke → acquire →
 Get/Put span tree.
 

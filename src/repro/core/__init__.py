@@ -20,14 +20,16 @@ Layers:
 * :mod:`repro.core.lint`         — DCheck static workflow linter (stable
   DF diagnostic codes; ``python -m repro.lint`` CLI).
 * :mod:`repro.core.check`        — DCheck dynamic invariant checker
-  (trace recording + offline happens-before/immutability validation).
+  (a trace recorder that rides on the DScope Tracer + offline
+  happens-before/immutability validation).
 * :mod:`repro.core.scale`        — DScale: rate-estimating pool
   autoscaler, SLO-aware prewarm budgets (container-seconds), and
   inhomogeneous (diurnal / bursty) arrival generators.
 * :mod:`repro.core.obs`          — DScope observability: MetricsRegistry,
-  per-request span Tracer (JSONL/Perfetto exporters), plan-vs-actual
-  attribution, and the standardized ``dflow-bench/v1`` schema
-  (``python -m repro.obs`` CLI).
+  per-request span Tracer (the data plane's one instrumentation hook,
+  which also carries DCheck's recorder; JSONL/Perfetto exporters),
+  plan-vs-actual attribution, and the standardized ``dflow-bench/v1``
+  schema (``python -m repro.obs`` CLI).
 """
 
 from .check import (TraceChecker, TraceEvent, TraceRecorder, Violation,

@@ -6,13 +6,15 @@ more weight than one sentence suggests: it is what makes duplicate
 what allows instance-scoped eviction to reclaim keys without a reader
 census.  This module makes those load-bearing invariants checkable:
 
-* :class:`TraceRecorder` — a thread-safe event log with a global logical
-  clock.  :class:`~repro.core.dstore.DStore` and
-  :class:`~repro.core.stream.StreamDirectory` carry a *zero-cost-when-off*
-  hook (``if self._tracer is not None``): attaching a recorder turns every
-  put / metadata publish / get / chunk publish / evict / node failure into
-  a :class:`TraceEvent`.  Events carry a content digest where the value is
-  digestable, so equality claims are checkable offline.
+* :class:`TraceRecorder` — a thread-safe event log ordered by a logical
+  clock.  It rides on the data plane's one instrumentation hook, the
+  DScope :class:`~repro.core.obs.Tracer`: a store given
+  ``attach_spans(Tracer(recorder=rec))`` turns every put / metadata
+  publish / get / chunk publish / evict / node failure into a
+  :class:`TraceEvent`, stamped on the tracer's clock (the one its spans
+  use); without a tracer each point costs one ``is None`` test.  Events
+  carry a content digest where the value is digestable, so equality
+  claims are checkable offline.
 * **Stress mode** — the recorder optionally injects tiny seeded random
   sleeps at instrumentation points (``stress=<seed>``), perturbing thread
   interleavings exactly where the data plane's ordering decisions are
@@ -49,7 +51,7 @@ import hashlib
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Iterable
+from typing import Any, Callable, Iterable
 
 __all__ = ["TraceEvent", "TraceRecorder", "Violation", "TraceChecker",
            "PlanConformance", "content_digest"]
@@ -157,12 +159,17 @@ class TraceRecorder:
         self._stress_max = float(stress_max_s)
 
     def record(self, kind: str, key: str = "", node: str = "", *,
+               clock: Callable[[], int] | None = None,
                idx: int | None = None, size: int = 0,
                digest: str | None = None, src: str = "",
                tier: str = "", hops: int = 0) -> TraceEvent:
+        """Append one event.  ``clock`` is the logical clock of the
+        :class:`~repro.core.obs.Tracer` that forwards it (read under this
+        log's lock, so the log stays in clock order); a recorder fed
+        directly numbers its own events."""
         delay = 0.0
         with self._lock:
-            self._clock += 1
+            self._clock = self._clock + 1 if clock is None else clock()
             ev = TraceEvent(self._clock, kind, key, node,
                             idx=idx, size=size, digest=digest,
                             src=src, tier=tier, hops=hops)
@@ -176,14 +183,6 @@ class TraceRecorder:
         if delay:
             time.sleep(delay)
         return ev
-
-    def tick(self) -> int:
-        """Advance and return the logical clock without recording an
-        event — lets DScope spans share this ordering domain so span
-        ``seq`` values interleave consistently with trace events."""
-        with self._lock:
-            self._clock += 1
-            return self._clock
 
     def events(self) -> list[TraceEvent]:
         with self._lock:

@@ -167,8 +167,7 @@ class InstanceRun:
         self.spans = spans if spans is not None else engine.spans
         self._span = None
         self._invoke_spans: list[Any] = []
-        if self.spans is not None and \
-                getattr(self.store, "_spans", None) is None:
+        if self.spans is not None and self.store.spans is None:
             self.store.attach_spans(self.spans)
         self._prewarm_timers: list[threading.Timer] = []
         # DScale prewarm budget (scale.py PrewarmBudget): when present,

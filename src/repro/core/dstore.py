@@ -47,7 +47,7 @@ class ImmutabilityError(ValueError):
 
 
 # stream.py lazily imports GetTimeout, so this import must come after it.
-from .check import TraceRecorder, content_digest  # noqa: E402
+from .check import content_digest  # noqa: E402
 from .stream import (DEFAULT_CHUNK, StreamDirectory, StreamReader,  # noqa: E402
                      StreamWriter, chunk_key, is_chunk_key)
 
@@ -297,13 +297,9 @@ class DStore:
         # with a failure (write → store wiped → publish) would register a
         # replica whose bytes are gone, invisible to recovery.
         self._write_lock = threading.Lock()
-        # DCheck hook (see check.py): None = recording off, zero cost.
-        self._tracer: TraceRecorder | None = None
-        # DScope hooks (see obs.py), same zero-cost-when-off pattern:
-        # _spans is a Tracer producing per-request span trees, _metrics a
-        # MetricsRegistry receiving hot-path latency observations.
-        self._spans = None
-        self._metrics = None
+        # The one instrumentation hook (attach_spans): a DScope Tracer,
+        # None = off, one ``is None`` test per instrumentation point.
+        self.spans = None
         # DPlan eviction hints: key -> Gets remaining before the key is
         # provably dead (installed per instance by set_plan_reads).  Own
         # lock so the countdown never nests inside _write_lock.
@@ -312,31 +308,19 @@ class DStore:
         self._peak_bytes = 0
         self._cowrite_checks = 0       # under _write_lock
 
-    def attach_tracer(self, tracer: TraceRecorder | None) -> None:
-        """Attach (or detach, with None) a :class:`TraceRecorder`.  Every
-        data-plane action is recorded from then on; stream-level events
-        (close/abort) are recorded by the shared StreamDirectory."""
-        self._tracer = tracer
-        self.streams.tracer = tracer
-
     def attach_spans(self, spans) -> None:
         """Attach (or detach, with None) a DScope span
-        :class:`~repro.core.obs.Tracer`.  Every Get/Put/chunk/evict from
-        then on emits a span parented under the calling thread's active
-        span (the function-invocation span the engine activated); a Get
-        nests its ``wait`` for the key's metadata, a Put its ``digest``
-        and the digest's per-leaf ``d2h`` spans (a co-write's, or every
-        Put's with a DCheck recorder attached)."""
-        self._spans = spans
-
-    def attach_metrics(self, registry) -> None:
-        """Attach a :class:`~repro.core.obs.MetricsRegistry` for hot-path
-        latency histograms (per stream chunk) *and* register the pull
-        collectors.  Passing None detaches the push hooks.  Get and Put
-        latency is timed by their DScope spans (:meth:`attach_spans`)."""
-        self._metrics = registry
-        if registry is not None:
-            self.register_metrics(registry)
+        :class:`~repro.core.obs.Tracer`, shared with the stream directory.
+        Every Get/Put/chunk/evict from then on emits a span parented
+        under the calling thread's active span (the function-invocation
+        span the engine activated); a Get nests its ``wait`` for the key's
+        metadata, a co-write's Put its ``digest`` and the digest's
+        per-leaf ``d2h`` spans.  A tracer with a DCheck recorder also
+        records every data-plane action as a trace event; a Put then
+        digests its value eagerly, since its ``put`` event carries the
+        digest."""
+        self.spans = spans
+        self.streams.spans = spans
 
     def register_metrics(self, registry) -> None:
         """Register pull-style collectors only (no hot-path cost): per-node
@@ -368,7 +352,7 @@ class DStore:
         instead of silently registering a second replica with different
         bytes.
         """
-        spans = self._spans
+        spans = self.spans
         if spans is None:
             return self._put(node, key, value)
         # Activated so the digest and its per-leaf spans nest under it.
@@ -377,7 +361,7 @@ class DStore:
 
     def _digest(self, key: str, value: Any) -> str | None:
         """Content digest of ``value``; traced, a ``digest`` span over it."""
-        spans = self._spans
+        spans = self.spans
         if spans is None:
             return content_digest(value)
         with spans.span(key, "digest"):
@@ -391,9 +375,11 @@ class DStore:
         """A Put's body against the directory that holds ``key``'s record
         (``where``: extra fields of DCheck's ``put`` event)."""
         store = self.stores[node]
-        tracer = self._tracer
-        # DCheck's put events carry the digest its checks compare.
-        digest = self._digest(key, value) if tracer is not None else None
+        spans = self.spans
+        # DCheck's put events carry the digest its checks compare; taken
+        # outside the span tree, which a recorder leaves as it is.
+        digest = content_digest(value) \
+            if spans is not None and spans.recorder is not None else None
         with self._write_lock:
             meta = directory.peek(key)
             if meta is not None:
@@ -403,9 +389,9 @@ class DStore:
             # Recorded before the bytes land so the trace's availability
             # event precedes any Get that could observe them.
             size = _sizeof(value)
-            if tracer is not None:
-                tracer.record("put", key, node, size=size, digest=digest,
-                              **where)
+            if spans is not None:
+                spans.record("put", key, node, size=size, digest=digest,
+                             **where)
             store.write(key, value)
             # Metadata publish is what wakes consumers; in the real system it
             # is asynchronous w.r.t. the producer container, here just cheap.
@@ -448,43 +434,37 @@ class DStore:
         until this thread holds the key's published metadata (at once for
         a local replica).
         """
-        spans = self._spans
+        spans = self.spans
         if spans is None:
-            return self._get_recorded(node, key, timeout)
-        sp = spans.start(key, "chunk" if is_chunk_key(key) else "get",
-                         node=node)
-        wait = spans.start(key, "wait", parent=sp, node=node)
-        try:
-            # Activated so cross-shard hop spans nest under this Get.
-            with spans.activate(sp):
-                value = self._get_recorded(
-                    node, key, timeout, woke=lambda: spans.end(wait))
-        except BaseException:
-            spans.end(wait, error=True)
-            spans.end(sp, error=True)
-            raise
-        spans.end(sp, size=_sizeof(value))
-        return value
-
-    def _get_recorded(self, node: str, key: str,
-                      timeout: float | None = None,
-                      woke: Callable[[], None] | None = None) -> Any:
-        tracer = self._tracer
-        if tracer is None:
-            value = self._get(node, key, timeout, woke)
+            value = self._get(node, key, timeout)
         else:
-            tracer.record("get_block", key, node)
-            try:
-                value = self._get(node, key, timeout, woke)
-            except BaseException:
-                tracer.record("get_fail", key, node)
-                raise
-            tracer.record("get_return", key, node,
-                          digest=content_digest(value))
+            value = self._get_traced(spans, node, key, timeout)
         # The plan countdown runs after get_return is recorded: the trace
         # shows this read completing before any eviction it triggers.
         if self._plan_reads:
             self._plan_note_read(key)
+        return value
+
+    def _get_traced(self, spans, node: str, key: str,
+                    timeout: float | None) -> Any:
+        sp = spans.start(key, "chunk" if is_chunk_key(key) else "get",
+                         node=node)
+        wait = spans.start(key, "wait", parent=sp, node=node)
+        spans.record("get_block", key, node)
+        try:
+            # Activated so cross-shard hop spans nest under this Get.
+            with spans.activate(sp):
+                value = self._get(node, key, timeout,
+                                  woke=lambda: spans.end(wait))
+        except BaseException:
+            spans.record("get_fail", key, node)
+            spans.end(wait, error=True)
+            spans.end(sp, error=True)
+            raise
+        if spans.recorder is not None:
+            spans.record("get_return", key, node,
+                         digest=content_digest(value))
+        spans.end(sp, size=_sizeof(value))
         return value
 
     def _get(self, node: str, key: str, timeout: float | None = None,
@@ -524,9 +504,9 @@ class DStore:
             # the lock a failure of `node` here would leave a phantom
             # replica that masks the data loss from recovery.
             with self._write_lock:
-                if self._tracer is not None:
-                    self._tracer.record("replica", key, node,
-                                        size=meta.size, digest=meta.digest)
+                if self.spans is not None:
+                    self.spans.record("replica", key, node,
+                                      size=meta.size, digest=meta.digest)
                 store.write(key, value)
                 self.directory.publish(key, meta.size, node,  # new replica
                                        digest=meta.digest)
@@ -552,7 +532,7 @@ class DStore:
         """One stream chunk: bytes in the local store, a directory record
         of its own (so remote pulls are chunk-granular and receiver-driven),
         and a stream-directory publish that wakes blocked readers."""
-        spans = self._spans
+        spans = self.spans
         if spans is None:
             return self._put_chunk(node, key, idx, chunk)
         sp = spans.start(chunk_key(key, idx), "chunk_put", node=node,
@@ -567,11 +547,11 @@ class DStore:
         ck = chunk_key(key, idx)
         digest = content_digest(chunk)
         with self._write_lock:
-            if self._tracer is not None:
-                self._tracer.record("put_chunk", key, node, idx=idx,
-                                    size=len(chunk), digest=digest)
-                self._tracer.record("put", ck, node, size=len(chunk),
-                                    digest=digest)
+            if self.spans is not None:
+                self.spans.record("put_chunk", key, node, idx=idx,
+                                  size=len(chunk), digest=digest)
+                self.spans.record("put", ck, node, size=len(chunk),
+                                  digest=digest)
             self.stores[node].write(ck, chunk)
             self.directory.publish(ck, len(chunk), node, digest=digest)
             self._note_peak()
@@ -606,16 +586,16 @@ class DStore:
         """Single-key eviction: reclaim the bytes on every node plus the
         directory record.  Safe exactly when no future Get of the key can
         exist — which is what the plan's liveness analysis proves."""
+        spans = self.spans
         with self._write_lock:
             existed = self.directory.peek(key) is not None
-            if self._tracer is not None and existed:
-                self._tracer.record("evict", key)
+            if spans is not None and existed:
+                spans.record("evict", key)
             for store in self.stores.values():
                 store.drop_key(key)
             self.directory.drop([key])
-        if existed and self._spans is not None:
-            self._spans.event(key, "evict", parent=None,
-                              trace=_trace_of(key))
+        if spans is not None and existed:
+            spans.event(key, "evict", parent=None, trace=_trace_of(key))
 
     def resident_bytes(self) -> int:
         """Bytes currently held across all node-local stores."""
@@ -649,24 +629,22 @@ class DStore:
         stores, directory records, and stream records (chunk keys share the
         instance prefix, so they are swept by the same pass).  Bounded
         memory under sustained multi-instance serving."""
+        spans = self.spans
         swept: list[str] = []
         with self._write_lock:
-            if self._tracer is not None or self._spans is not None:
+            if spans is not None:
                 # Recorded before the bytes are reclaimed: an in-flight
                 # reader recorded earlier is a real use-after-evict hazard.
-                for k in self.directory.keys():
-                    if k.startswith(prefix):
-                        if self._tracer is not None:
-                            self._tracer.record("evict", k)
-                        swept.append(k)
+                swept = [k for k in self.directory.keys()
+                         if k.startswith(prefix)]
+                for k in swept:
+                    spans.record("evict", k)
             for store in self.stores.values():
                 store.drop_prefix(prefix)
             self.directory.drop_prefix(prefix)
         self.streams.evict_prefix(prefix)
-        if self._spans is not None:
-            for k in swept:
-                self._spans.event(k, "evict", parent=None,
-                                  trace=_trace_of(k))
+        for k in swept:
+            spans.event(k, "evict", parent=None, trace=_trace_of(k))
         if self._plan_reads:
             with self._plan_lock:
                 for k in [k for k in self._plan_reads
@@ -679,12 +657,13 @@ class DStore:
         # Open streams abort (blocked readers get a clean error); closed
         # streams are evicted so a recovery rerun can re-claim them.
         self.streams.fail_owner(node)
+        spans = self.spans
         with self._write_lock:
-            if self._tracer is not None:
-                self._tracer.record("fail_node", node=node)
+            if spans is not None:
+                spans.record("fail_node", node=node)
             self.stores[node].drop_all()
             lost = self.directory.drop_node(node)
-            if self._tracer is not None:
+            if spans is not None:
                 for k in lost:
-                    self._tracer.record("drop", k, node)
+                    spans.record("drop", k, node)
             return lost

@@ -15,18 +15,18 @@ through:
 * :class:`MetricsRegistry` — thread-safe counters / gauges / histograms
   with label sets.  Subsystems register *collectors* (pull-style scrape
   callbacks, zero hot-path cost) via their ``register_metrics`` methods;
-  hot-path latency histograms (per stream chunk) are pushed only when a
-  registry is *attached* (``attach_metrics``, mirroring the DCheck
-  ``attach_tracer`` zero-cost-when-off pattern).
+  nothing on the data plane pushes into a registry.
 * :class:`Tracer` / :class:`Span` — per-request span trees:
   request → function invocation → container acquire / execution slot /
   body → per-Get (its wait for the key) / per-Put (its digest, and the
   device → host copy of each array leaf) → per-chunk stream transfer →
   cross-shard hop; beside the tree, each arrival's admission lag.
-  Ordering comes from a logical clock (optionally shared with DCheck's
-  :class:`~repro.core.check.TraceRecorder` so spans and invariant events
-  interleave consistently); durations come from an injectable clock —
-  wall clock in the threaded engine, ``env.now`` in the simulator.
+  The tracer is the data plane's one instrumentation hook: DCheck's
+  :class:`~repro.core.check.TraceRecorder` rides on it
+  (``Tracer(recorder=...)``), and its invariant events are stamped on the
+  same logical clock as span ``seq`` values.  Durations come from an
+  injectable clock — wall clock in the threaded engine, ``env.now`` in
+  the simulator.
 * Exporters — JSON-lines (:func:`write_spans_jsonl` /
   :func:`read_spans_jsonl`, with the plan attribution doc embedded as a
   meta line so a span file is self-contained) and Chrome ``trace_event``
@@ -351,15 +351,17 @@ class Tracer:
     activity on the same axis, so device idle gaps can be joined with the
     spans open during them.  ``start(..., start=t)`` backdates a span to a
     reading ``t`` of the same clock (an arrival's due time).
-    ``recorder`` shares DCheck's :class:`~repro.core.check.TraceRecorder`
-    logical clock so span ``seq`` values interleave consistently with
-    invariant-trace events; without one the tracer counts on its own.
+
+    ``recorder`` is DCheck's :class:`~repro.core.check.TraceRecorder`:
+    :meth:`record` hands it each data-plane event, stamped on this
+    tracer's logical clock, so span ``seq`` values and invariant events
+    interleave on one order.  Without one :meth:`record` returns at once.
     """
 
     def __init__(self, clock: Callable[[], float] = time.monotonic, *,
                  recorder=None):
         self._clock = clock
-        self._recorder = recorder
+        self.recorder = recorder
         self._lock = threading.Lock()
         self._seq = 0
         self._next_id = 0
@@ -370,11 +372,17 @@ class Tracer:
         self._clock = clock
 
     def _tick(self) -> int:
-        if self._recorder is not None:
-            return self._recorder.tick()
         with self._lock:
             self._seq += 1
             return self._seq
+
+    def record(self, kind: str, key: str = "", node: str = "",
+               **fields: Any) -> None:
+        """One DCheck event (:meth:`TraceRecorder.record
+        <repro.core.check.TraceRecorder.record>`), stamped on this
+        tracer's clock; a no-op without a recorder."""
+        if self.recorder is not None:
+            self.recorder.record(kind, key, node, clock=self._tick, **fields)
 
     # -- span lifecycle ----------------------------------------------------
     def start(self, name: str, kind: str = "span", *,

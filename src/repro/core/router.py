@@ -312,8 +312,8 @@ class ShardedDStore(DStore):
     def __init__(self, nodes: list[str], transport: Transport | None = None,
                  *, coordinator: Coordinator | None = None):
         # Base init wires streams/stores/transport and — under the test
-        # harness — auto-attaches the DCheck tracer (conftest patches
-        # DStore.__init__, which this super() call resolves to).
+        # harness — auto-attaches a DCheck-recording Tracer (conftest
+        # patches DStore.__init__, which this super() call resolves to).
         super().__init__(nodes, transport)
         self.node_list = list(nodes)
         self.shards: dict[str, DataDirectoryService] = {
@@ -408,7 +408,7 @@ class ShardedDStore(DStore):
 
     # -- Table 1 core API, sharded ----------------------------------------
     # _put/_put_chunk/_get are the inner methods: the base class's public
-    # put/put_chunk/get wrappers add the DScope span/metric hooks once, so
+    # put/put_chunk/get wrappers add the span and DCheck hooks once, so
     # sharded stores are instrumented identically to the single store.
     def _put(self, node: str, key: str, value) -> None:
         home = self._home_for_put(node, key)
@@ -420,11 +420,11 @@ class ShardedDStore(DStore):
         ck = chunk_key(key, idx)
         digest = content_digest(chunk)
         with self._write_lock:
-            if self._tracer is not None:
-                self._tracer.record("put_chunk", key, node, idx=idx,
-                                    size=len(chunk), digest=digest, src=home)
-                self._tracer.record("put", ck, node, size=len(chunk),
-                                    digest=digest, src=home)
+            if self.spans is not None:
+                self.spans.record("put_chunk", key, node, idx=idx,
+                                  size=len(chunk), digest=digest, src=home)
+                self.spans.record("put", ck, node, size=len(chunk),
+                                  digest=digest, src=home)
             self.stores[node].write(ck, chunk)
             self.shards[home].publish(ck, len(chunk), node, digest=digest)
             self._note_peak()
@@ -507,7 +507,7 @@ class ShardedDStore(DStore):
             shard.drop_replica(key, src)    # phantom replica
             return _MISSING
         tier = TIER_MEM if src == node else TIER_NET
-        spans = self._spans
+        spans = self.spans
         sp = spans.start(key, "hop", src=home, tier=tier, hops=hops,
                          size=meta.size) if spans is not None else None
         try:
@@ -517,11 +517,11 @@ class ShardedDStore(DStore):
                 spans.end(sp)
             shard.release_replica(key, src)
         with self._write_lock:
-            if self._tracer is not None:
-                self._tracer.record("replica", key, node, size=meta.size,
-                                    digest=meta.digest, src=home)
-                self._tracer.record("route", key, node, size=meta.size,
-                                    src=home, tier=tier, hops=hops)
+            if spans is not None:
+                spans.record("replica", key, node, size=meta.size,
+                             digest=meta.digest, src=home)
+                spans.record("route", key, node, size=meta.size,
+                             src=home, tier=tier, hops=hops)
             store.write(key, value)
             shard.publish(key, meta.size, node, digest=meta.digest)
             self._note_peak()
@@ -540,41 +540,38 @@ class ShardedDStore(DStore):
 
     # -- eviction, sharded -------------------------------------------------
     def evict_key(self, key: str) -> None:
+        spans = self.spans
         with self._write_lock:
             existed = any(sh.peek(key) is not None
                           for sh in self.shards.values())
-            if self._tracer is not None and existed:
-                self._tracer.record("evict", key)
+            if spans is not None and existed:
+                spans.record("evict", key)
             for store in self.stores.values():
                 store.drop_key(key)
             for shard in self.shards.values():
                 shard.drop([key])
-        if existed and self._spans is not None:
-            self._spans.event(key, "evict", parent=None,
-                              trace=_trace_of(key))
+        if spans is not None and existed:
+            spans.event(key, "evict", parent=None, trace=_trace_of(key))
         # Routes are left installed: keys are immutable, so a stale route
         # for an evicted key can only lead to a clean block, never stale
         # bytes.
 
     def evict_instance(self, prefix: str) -> None:
+        spans = self.spans
         swept: list[str] = []
         with self._write_lock:
-            if self._tracer is not None or self._spans is not None:
-                for shard in self.shards.values():
-                    for k in shard.keys():
-                        if k.startswith(prefix):
-                            if self._tracer is not None:
-                                self._tracer.record("evict", k)
-                            swept.append(k)
+            if spans is not None:
+                swept = [k for shard in self.shards.values()
+                         for k in shard.keys() if k.startswith(prefix)]
+                for k in swept:
+                    spans.record("evict", k)
             for store in self.stores.values():
                 store.drop_prefix(prefix)
             for shard in self.shards.values():
                 shard.drop_prefix(prefix)
         self.streams.evict_prefix(prefix)
-        if self._spans is not None:
-            for k in swept:
-                self._spans.event(k, "evict", parent=None,
-                                  trace=_trace_of(k))
+        for k in swept:
+            spans.event(k, "evict", parent=None, trace=_trace_of(k))
         self.coordinator.remove_prefix(prefix)
         if self._plan_reads:
             with self._plan_lock:
@@ -590,10 +587,10 @@ class ShardedDStore(DStore):
         re-homes them — bounded work, no directory-wide scan); the rest
         are lost and must be recomputed."""
         self.streams.fail_owner(node)
+        spans = self.spans
         with self._write_lock:
-            tracer = self._tracer
-            if tracer is not None:
-                tracer.record("fail_node", node=node)
+            if spans is not None:
+                spans.record("fail_node", node=node)
             self.stores[node].drop_all()
             self.coordinator.mark_failed(node)
             lost: list[str] = []
@@ -616,9 +613,9 @@ class ShardedDStore(DStore):
                     continue
                 new_home = survivors[0]
                 for n in survivors:
-                    if tracer is not None:
-                        tracer.record("publish", k, n, size=m.size,
-                                      digest=m.digest, src=new_home)
+                    if spans is not None:
+                        spans.record("publish", k, n, size=m.size,
+                                     digest=m.digest, src=new_home)
                     self.shards[new_home].publish(k, m.size, n,
                                                   digest=m.digest)
                 self.coordinator.rehome(k, new_home)
@@ -627,7 +624,7 @@ class ShardedDStore(DStore):
             self.shards[node] = DataDirectoryService()
             self.coordinator.mark_alive(node)
             lost = sorted(set(lost))
-            if tracer is not None:
+            if spans is not None:
                 for k in lost:
-                    tracer.record("drop", k, node)
+                    spans.record("drop", k, node)
             return lost

@@ -312,13 +312,14 @@ class ContainerService:
         # service shut down or the node died.
         self.closed = False
         self._failed_nodes: set[str] = set()
-        # DCheck hook: container lifecycle events land in the same trace
-        # as data-plane events, so PlanConformance can judge whether a
-        # cold boot was avoidable (an unleased container existed).
-        self._tracer = None
+        # The DScope Tracer (attach_spans): through its DCheck recorder,
+        # container lifecycle events land in the same trace as data-plane
+        # events, so PlanConformance can judge whether a cold boot was
+        # avoidable (an unleased container existed).
+        self.spans = None
 
-    def attach_tracer(self, tracer) -> None:
-        self._tracer = tracer
+    def attach_spans(self, spans) -> None:
+        self.spans = spans
 
     def register_metrics(self, registry) -> None:
         """DScope pull collector: per-(node, image) lifecycle counters
@@ -350,7 +351,7 @@ class ContainerService:
                      released: bool = False) -> None:
         # Called with self._lock held, right after a pool transition;
         # translates counter deltas into trace events (key = image).
-        tr = self._tracer
+        tr = self.spans
         warm0, pw0, ev0, pb0 = pre
         if released:
             tr.record("container_release", image, node)
@@ -385,7 +386,7 @@ class ContainerService:
             p = self.pool(node, image, cold_start)
             pre = (p.warm_hits, p.prewarm_hits, p.evictions, p.prewarm_boots)
             lease = p.acquire(self._clock())
-            if self._tracer is not None:
+            if self.spans is not None:
                 self._pool_events(p, pre, node, image, cold=lease.cold)
         if lease.delay > 0:
             self._sleep(lease.delay)
@@ -401,7 +402,7 @@ class ContainerService:
                 return
             pre = (p.warm_hits, p.prewarm_hits, p.evictions, p.prewarm_boots)
             p.release(lease, self._clock())
-            if self._tracer is not None:
+            if self.spans is not None:
                 self._pool_events(p, pre, node, image, released=True)
 
     def prewarm(self, node: str, image: str,
@@ -419,7 +420,7 @@ class ContainerService:
             pre = (p.warm_hits, p.prewarm_hits, p.evictions, p.prewarm_boots)
             p.prewarm(self._clock())
             booted = p.prewarm_boots > pre[3]
-            if self._tracer is not None:
+            if self.spans is not None:
                 self._pool_events(p, pre, node, image)
         return booted
 
@@ -433,7 +434,7 @@ class ContainerService:
             p = self.pool(node, image, cold_start)
             pre = (p.warm_hits, p.prewarm_hits, p.evictions, p.prewarm_boots)
             out = p.set_target(target, self._clock())
-            if self._tracer is not None:
+            if self.spans is not None:
                 self._pool_events(p, pre, node, image)
         return out
 
@@ -668,19 +669,22 @@ class DServe:
     DScope (obs.py): every DServe owns a :class:`MetricsRegistry` wired
     with pull collectors (containers, store, routing) — ``ServeReport``
     is built from it, and ``self.metrics.collect()`` dumps every counter
-    from one source.  Passing your own ``metrics`` registry additionally
-    enables the push-side hot-path histograms (per-chunk latency);
-    passing a ``spans`` :class:`~repro.core.obs.Tracer` records
-    per-request span trees (request → invoke → acquire / slot / exec →
-    Get/Put → chunk → hop) and each arrival's ``admit`` span (due time →
-    launch).  Both default to off-path: a plain DServe pays nothing.
+    from one source (``metrics`` brings your own registry).  Passing a
+    ``spans`` :class:`~repro.core.obs.Tracer` records per-request span
+    trees (request → invoke → acquire / slot / exec → Get/Put → chunk →
+    hop) and each arrival's ``admit`` span (due time → launch); a tracer
+    built with a DCheck recorder (``Tracer(recorder=TraceRecorder())``)
+    also records the data-plane and container events that
+    :class:`~repro.core.check.TraceChecker` and
+    :class:`~repro.core.check.PlanConformance` replay.  Without a tracer
+    a plain DServe pays nothing.
     """
 
     def __init__(self, wf, *, n_nodes: int = 2, pattern: str = "dataflow",
                  prewarm: bool | None = None, keepalive: float = 600.0,
                  max_per_node: int = 8, cold_start: float | None = None,
                  transport=None, get_timeout: float = 30.0,
-                 evict_on_complete: bool = True, tracer=None,
+                 evict_on_complete: bool = True,
                  lint: bool = True, plan=None, sharded: bool = False,
                  metrics=None, spans=None, max_inflight: int | None = None,
                  queue_depth: int | None = None, autoscale=None,
@@ -710,21 +714,15 @@ class DServe:
         self.sharded = sharded
         store_cls = ShardedDStore if sharded else DStore
         self.store = store_cls(self.engine.nodes, self.engine.transport)
-        if tracer is not None:
-            self.store.attach_tracer(tracer)
-            self.containers.attach_tracer(tracer)
         # DScope wiring: pull collectors always (they cost nothing until
-        # collect()); the hot-path push hooks only when the caller brought
-        # a registry of their own.
+        # collect()); the store and the containers share the one Tracer.
         self.spans = spans
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.containers.register_metrics(self.metrics)
-        if metrics is not None:
-            self.store.attach_metrics(self.metrics)
-        else:
-            self.store.register_metrics(self.metrics)
+        self.store.register_metrics(self.metrics)
         if spans is not None:
             self.store.attach_spans(spans)
+            self.containers.attach_spans(spans)
         self.placement = self.engine.gs.assign(wf)
         if plan is True:
             from .plan import build_plan

@@ -106,10 +106,10 @@ class StreamDirectory:
         self._cv = threading.Condition(self._lock)
         self._streams: dict[str, _StreamMeta] = {}
         self._plain: set[str] = set()         # keys Put monolithically
-        # DCheck hook (see check.py): set via DStore.attach_tracer.  Chunk
-        # publishes are recorded by DStore.put_chunk; the directory records
-        # the stream-lifecycle events (close/abort) it alone decides.
-        self.tracer = None
+        # The store's Tracer (DStore.attach_spans).  Chunk publishes are
+        # recorded by DStore.put_chunk; the directory records the DCheck
+        # stream-lifecycle events (close/abort) it alone decides.
+        self.spans = None
 
     # -- producer ----------------------------------------------------------
     def claim(self, key: str, node: str) -> None:
@@ -135,10 +135,10 @@ class StreamDirectory:
     def close(self, key: str, total: int) -> None:
         """Seal the stream at ``total`` chunks (first closer wins)."""
         with self._cv:
-            if self.tracer is not None:
+            if self.spans is not None:
                 # Every close attempt is recorded (not just the winning
                 # one) so divergent co-closer totals are checkable.
-                self.tracer.record("stream_close", key, size=total)
+                self.spans.record("stream_close", key, size=total)
             m = self._streams[key]
             if m.total is None:
                 m.total = total
@@ -158,8 +158,8 @@ class StreamDirectory:
                     self._cv.notify_all()
                     return
             m.aborted = True
-            if self.tracer is not None:
-                self.tracer.record("stream_abort", key, node or "")
+            if self.spans is not None:
+                self.spans.record("stream_abort", key, node or "")
             self._cv.notify_all()
 
     def notify_plain(self, key: str) -> None:
@@ -193,8 +193,8 @@ class StreamDirectory:
                     continue            # a co-writer is still alive
                 if m.total is None:
                     m.aborted = True
-                    if self.tracer is not None:
-                        self.tracer.record("stream_abort", k, node)
+                    if self.spans is not None:
+                        self.spans.record("stream_abort", k, node)
                 else:
                     del self._streams[k]
             self._cv.notify_all()
@@ -374,7 +374,7 @@ class StreamReader:
         # context active *here* (the consumer's invocation span) is
         # captured explicitly and re-activated inside the pump — the
         # per-chunk Get spans it emits then parent correctly.
-        spans = getattr(self._store, "_spans", None)
+        spans = self._store.spans
         self._span_parent = spans.current() if spans is not None else None
         mode = self._store.streams.wait_mode(self.key, self.timeout)
         if mode == "plain":
@@ -387,21 +387,15 @@ class StreamReader:
                                   name=f"dstream-pull-{self.key}")
             th.start()
 
-    def _observe_chunk(self, elapsed: float) -> None:
-        metrics = getattr(self._store, "_metrics", None)
-        if metrics is not None:
-            metrics.histogram("stream_chunk_seconds").observe(elapsed)
-
     def _pump(self) -> None:
         assert self._queue is not None
-        spans = getattr(self._store, "_spans", None)
+        spans = self._store.spans
         ctx = spans.activate(self._span_parent) if spans is not None \
             else nullcontext()
         i = 0
         try:
             with ctx:
                 while True:
-                    t0 = time.monotonic()
                     size = self._store.streams.wait_chunk(self.key, i,
                                                           self.timeout)
                     if size is None:
@@ -410,21 +404,18 @@ class StreamReader:
                     data = self._store.get(self.node,
                                            chunk_key(self.key, i),
                                            timeout=self.timeout)
-                    self._observe_chunk(time.monotonic() - t0)
                     self._queue.put(data)
                     i += 1
         except BaseException as exc:          # noqa: BLE001 - hand to reader
             self._queue.put(exc)
 
     def _next_sync(self) -> Any:
-        t0 = time.monotonic()
         size = self._store.streams.wait_chunk(self.key, self._idx,
                                               self.timeout)
         if size is None:
             raise StopIteration
         data = self._store.get(self.node, chunk_key(self.key, self._idx),
                                timeout=self.timeout)
-        self._observe_chunk(time.monotonic() - t0)
         self._idx += 1
         return data
 

@@ -184,6 +184,7 @@ def sharded_run(seed: int, n_nodes: int, *, pattern: str = "dataflow",
     against the oracle/baseline and runs the TraceChecker."""
     from repro.core.check import TraceRecorder
     from repro.core.dscheduler import DFlowEngine
+    from repro.core.obs import Tracer
     from repro.core.router import ShardedDStore
 
     wf = random_workflow(seed)
@@ -191,7 +192,7 @@ def sharded_run(seed: int, n_nodes: int, *, pattern: str = "dataflow",
                       sharded=True)
     store = ShardedDStore(eng.nodes, eng.transport)
     rec = TraceRecorder(stress=stress)
-    store.attach_tracer(rec)
+    store.attach_spans(Tracer(recorder=rec))
     rep = eng.start(wf, external_inputs(wf), store=store).wait()
     outputs = {k: bytes(v) for k, v in rep.outputs.items()}
     return outputs, store, rec.events()

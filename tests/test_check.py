@@ -15,6 +15,7 @@ The module is marked ``notracecheck``: it seeds violations on purpose, so
 the conftest's global DFLOW_TRACE_CHECK teardown must not re-judge them.
 """
 
+import collections
 import threading
 import time
 
@@ -23,6 +24,7 @@ import pytest
 from repro.core.check import (TraceChecker, TraceEvent, TraceRecorder,
                               content_digest)
 from repro.core.dstore import DStore, Transport
+from repro.core.obs import Tracer
 
 pytestmark = pytest.mark.notracecheck
 
@@ -232,7 +234,7 @@ def test_aborted_stream_not_judged():
 def traced_store(nodes, stress=None, transport=None):
     ds = DStore(nodes, transport)
     rec = TraceRecorder(stress=stress)
-    ds.attach_tracer(rec)
+    ds.attach_spans(Tracer(recorder=rec))
     return ds, rec
 
 
@@ -301,7 +303,7 @@ def _engine_run_traced(seed, stress):
     eng = DFlowEngine(n_nodes=3)
     ds = DStore(eng.nodes, eng.transport)
     rec = TraceRecorder(stress=stress)
-    ds.attach_tracer(rec)
+    ds.attach_spans(Tracer(recorder=rec))
     rep = eng.start(wf, external_inputs(wf), store=ds).wait()
     assert rep.outputs == oracle_run(wf, external_inputs(wf))
     return rec
@@ -320,7 +322,7 @@ def test_serve_run_traces_clean_under_stress():
 
     rec = TraceRecorder(stress=7)
     srv = DServe(BENCHMARKS["Srv"](), n_nodes=2, cold_start=0.01,
-                 tracer=rec)
+                 spans=Tracer(recorder=rec))
     rep = srv.run([0.0, 0.05, 0.1, 0.15],
                   inputs=lambda i: {"request": b"r%d" % i})
     assert rep.failures == 0 and len(rep.stats) == 4
@@ -349,3 +351,90 @@ def test_stress_mode_is_deterministically_seeded():
         a.record("put", "k", "n")
         b.record("put", "k", "n")
     assert a._stress == b._stress
+
+
+# ----------------------------------------------------------------------
+# One hook: the recorder rides on the DScope Tracer.
+# ----------------------------------------------------------------------
+
+def _bound_wordcount(shards=4):
+    """The built-in WordCount with a deterministic body per function."""
+    import dataclasses
+    import hashlib
+
+    from repro.core.dag import Workflow
+    from repro.core.workloads import wordcount
+
+    def body(outs):
+        def fn(**kw):
+            h = hashlib.sha256()
+            for k in sorted(kw):
+                h.update(k.encode() + bytes(kw[k]))
+            return {o: h.digest() + o.encode() for o in outs}
+        return fn
+
+    wc = wordcount(shards)
+    return Workflow(wc.name, [
+        dataclasses.replace(f, fn=body(f.outputs), exec_time=0.0,
+                            cold_start=0.0)
+        for f in wc.functions.values()], {"corpus": 64})
+
+
+def _serve_wordcount(spans, sharded):
+    from repro.core.serve import DServe
+
+    # Control flow: every Get finds its key published, so the trace is
+    # the same from run to run.
+    srv = DServe(_bound_wordcount(), n_nodes=2, pattern="controlflow",
+                 cold_start=0.0, sharded=sharded, spans=spans)
+    rep = srv.run([0.0], inputs={"corpus": b"the quick brown fox"})
+    assert rep.failures == 0
+    # run() returns once the sink is out; the last container release and
+    # span ends land a beat later.
+    for _ in range(500):
+        done = spans.finished()
+        ids = {s.id for s in done}
+        rec = spans.recorder
+        released = rec is None or (
+            sum(e.kind == "container_release" for e in rec.events()) ==
+            sum(e.kind == "cold_boot" for e in rec.events()))
+        if released and all(s.parent is None or s.parent in ids
+                            for s in done):
+            break
+        time.sleep(0.002)
+    by_id = {s.id: s for s in done}
+    return collections.Counter(
+        (s.kind, s.name, by_id[s.parent].kind if s.parent else None)
+        for s in done)
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["DStore", "ShardedDStore"])
+def test_recorder_on_the_tracer_leaves_spans_and_events_as_they_were(
+        sharded):
+    rec = TraceRecorder()
+    tr = Tracer(recorder=rec)
+    spans = _serve_wordcount(tr, sharded)
+    # (a) The recorder changes no span: kind, name and parent kind.
+    assert spans == _serve_wordcount(Tracer(), sharded)
+    # (b) DCheck's events per key, as a DServe(tracer=rec) run recorded
+    # them before the recorder moved onto the tracer.
+    kinds: dict[str, collections.Counter] = collections.defaultdict(
+        collections.Counter)
+    for e in rec.events():
+        kinds[e.key][e.kind] += 1
+    data = ["corpus", "result"] + [f"{p}.{i}" for p in ("shard", "wc")
+                                   for i in range(4)]
+    images = ["split", "merge"] + [f"count.{i}" for i in range(4)]
+    expected = {f"WC#0:{k}": {"put": 1, "get_block": 1, "get_return": 1,
+                              "evict": 1} for k in data}
+    expected.update({f"WC/{f}": {"cold_boot": 1, "container_release": 1}
+                     for f in images})
+    assert {k: dict(v) for k, v in kinds.items()} == expected
+    # (c) The trace is clean.
+    TraceChecker().check_or_raise(rec.events())
+    # One clock: span ticks and event stamps never collide.
+    ticks = [e.clock for e in rec.events()]
+    for s in tr.finished():
+        ticks += [s.seq] if s.end_seq == s.seq else [s.seq, s.end_seq]
+    assert len(ticks) == len(set(ticks))

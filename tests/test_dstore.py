@@ -10,7 +10,7 @@ import pytest
 from repro.core.check import TraceRecorder, content_digest
 from repro.core.dstore import (DStore, GetTimeout, ImmutabilityError,
                                Transport)
-from repro.core.obs import MetricsRegistry
+from repro.core.obs import MetricsRegistry, Tracer
 from repro.core.router import ShardedDStore
 
 
@@ -143,7 +143,7 @@ class CountedLeaf:
 
 def untraced(make):
     store = make(["n0", "n1"])
-    store.attach_tracer(None)       # the premise: no DCheck recorder
+    store.attach_spans(None)        # the premise: no DCheck recorder
     return store
 
 
@@ -202,7 +202,7 @@ def test_cowrite_checks_count_exactly_the_cowrites(make):
 def test_traced_put_events_carry_the_digest(make):
     store = make(["n0", "n1"])
     rec = TraceRecorder()
-    store.attach_tracer(rec)
+    store.attach_spans(Tracer(recorder=rec))
     leaf = CountedLeaf(np.arange(6.0))
     store.put("n0", "k", {"kv": leaf})
     (put,) = [e for e in rec.events() if e.kind == "put"]

@@ -193,10 +193,11 @@ def test_sharded_hop_spans_nested_under_gets():
     assert routed >= len(hops)
 
 
+@pytest.mark.notracecheck       # the premise: a store with no tracer
 def test_zero_cost_when_detached():
-    """No hooks attached: the store carries None hooks and works."""
+    """No hook attached: the store's one hook is None and it works."""
     store = DStore(["node0"])
-    assert store._spans is None and store._metrics is None
+    assert store.spans is None and store.streams.spans is None
     store.put("node0", "k", b"v")
     assert bytes(store.get("node0", "k")) == b"v"
 
@@ -282,8 +283,8 @@ def test_wait_ends_after_the_producers_put_publishes():
     order.)"""
     tr = Tracer()
     store = DStore(["node0", "node1"])
-    store.attach_tracer(None)       # DCheck's recorder digests every Put
-    store.attach_spans(tr)
+    store.attach_spans(None)    # the premise: no DCheck recorder (it
+    store.attach_spans(tr)      # digests every Put) rides on the tracer
     got = []
     consumer = threading.Thread(
         target=lambda: got.append(store.get("node1", "W#0:k", timeout=10)))
@@ -319,8 +320,8 @@ def test_wait_ends_after_the_producers_put_publishes():
 def test_digest_covers_one_d2h_per_array_leaf():
     tr = Tracer()
     store = DStore(["node0", "node1"])
-    store.attach_tracer(None)       # DCheck's recorder digests every Put
-    store.attach_spans(tr)
+    store.attach_spans(None)    # the premise: no DCheck recorder (it
+    store.attach_spans(tr)      # digests every Put) rides on the tracer
 
     def value():
         return {"k": np.ones((32, 16), np.float32),
@@ -393,6 +394,7 @@ def test_admit_starts_at_the_due_time_and_latency_excludes_it():
     assert a0.duration < 0.1
 
 
+@pytest.mark.notracecheck       # the premise: stores with no tracer
 def test_no_span_is_created_without_a_tracer(monkeypatch):
     made = []
 
@@ -413,7 +415,6 @@ def test_no_span_is_created_without_a_tracer(monkeypatch):
     # The same run with a tracer does make them, through the same class;
     # its first Puts digest nothing, and a co-write makes the digest spans.
     traced = DServe(wf, n_nodes=2, cold_start=0.0, spans=Tracer())
-    traced.store.attach_tracer(None)    # DCheck's recorder digests
     traced.run([0.0], {"request": np.arange(16.0)})
     assert {"admit", "request", "invoke", "slot", "exec", "get", "wait",
             "put"} <= set(made)
@@ -640,7 +641,7 @@ def check_obs_enabled_differential(seed):
     tr, reg = Tracer(), MetricsRegistry()
     engine = DFlowEngine(n_nodes=2, get_timeout=30.0, spans=tr)
     store = DStore(engine.nodes, engine.transport)
-    store.attach_metrics(reg)
+    store.register_metrics(reg)
     rep = engine.start(wf, ext, store=store).wait()
     got = {k: bytes(v) for k, v in rep.outputs.items()}
     assert got == expected, f"seed {seed}"

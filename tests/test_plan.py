@@ -30,6 +30,7 @@ from repro.core.check import PlanConformance, TraceEvent, TraceRecorder
 from repro.core.dag import FunctionSpec, Workflow
 from repro.core.dscheduler import DFlowEngine
 from repro.core.dstore import DStore
+from repro.core.obs import Tracer
 from repro.core.partition import cut_bytes, partition_workflow
 from repro.core.plan import build_plan
 from repro.core.workloads import BENCHMARKS, serving_chain
@@ -132,7 +133,7 @@ def check_plan_run_conforms(seed, *, stream_prob=0.15):
     plan = build_plan(wf, placement)
     store = DStore(engine.nodes, engine.transport)
     rec = TraceRecorder()
-    store.attach_tracer(rec)
+    store.attach_spans(Tracer(recorder=rec))
     rep = engine.start(wf, ext, store=store, placement=placement,
                        plan=plan).wait()
     # (1) eviction never destroyed bytes anyone needed: byte-exact vs
@@ -199,13 +200,13 @@ def test_plan_rejects_straggler_and_failure_modes():
 # Plan-driven serving: bounded resident bytes + prewarm conformance
 # ----------------------------------------------------------------------
 
-def _serve(plan, tracer=None, n=6):
+def _serve(plan, spans=None, n=6):
     from repro.core.serve import DServe
 
     wf = serving_chain(stages=4, exec_time=0.02, cold_start=0.08,
                        payload=16 * 1024)
     srv = DServe(wf, n_nodes=2, pattern="dataflow", keepalive=10.0,
-                 max_per_node=16, plan=plan, tracer=tracer)
+                 max_per_node=16, plan=plan, spans=spans)
     arrivals = [i * 0.05 for i in range(n)]
     rep = srv.run(arrivals, inputs={"request": b"req"})
     assert rep.failures == 0, [s.error for s in rep.stats if not s.ok]
@@ -223,7 +224,7 @@ def test_serve_plan_bounds_resident_bytes():
 
 def test_serve_plan_trace_conforms():
     rec = TraceRecorder()
-    rep, srv = _serve(plan=True, tracer=rec, n=4)
+    rep, srv = _serve(plan=True, spans=Tracer(recorder=rec), n=4)
     PlanConformance(srv.plan).check_or_raise(
         rec.events(), instances=[s.instance for s in rep.stats])
     kinds = {e.kind for e in rec.events()}

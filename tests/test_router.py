@@ -12,6 +12,7 @@ from strategies import external_inputs, random_workflow
 
 from repro.core.check import TraceChecker, TraceRecorder
 from repro.core.dstore import DStore, GetTimeout
+from repro.core.obs import Tracer
 from repro.core.partition import partition_workflow, stage_node
 from repro.core.plan import build_plan
 from repro.core.router import (TIER_IPC, TIER_MEM, TIER_NET, Coordinator,
@@ -116,7 +117,7 @@ def test_table_miss_resolves_in_one_hop():
     counts as a 1-hop resolution."""
     store = ShardedDStore(NODES)
     rec = TraceRecorder()
-    store.attach_tracer(rec)
+    store.attach_spans(Tracer(recorder=rec))
     store.put("n1", "k", b"v" * 100)          # dynamic home: n1
     assert store.get("n2", "k", timeout=5.0) == b"v" * 100
     assert store.hop_hist[1] == 1 and store.hop_hist[2] == 0
@@ -135,7 +136,7 @@ def test_table_miss_resolves_in_one_hop():
 def test_misroute_costs_one_extra_hop_and_is_flagged():
     store = ShardedDStore(NODES)
     rec = TraceRecorder()
-    store.attach_tracer(rec)
+    store.attach_spans(Tracer(recorder=rec))
     store.put("n1", "k", b"payload")          # home: n1
     # Poison the consumer's table: stale route pointing at an ALIVE shard
     # that is not the key's home.

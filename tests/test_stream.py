@@ -415,6 +415,7 @@ def test_sharded_mid_stream_failure_heals_from_producing_shard():
     directory-wide scan) — and every post-failure Get still resolves in
     at most one hop (a failure re-home is not a misroute)."""
     from repro.core.check import TraceChecker, TraceRecorder
+    from repro.core.obs import Tracer
     from repro.core.router import ShardedDStore
 
     calls: dict[str, int] = {}
@@ -438,7 +439,7 @@ def test_sharded_mid_stream_failure_heals_from_producing_shard():
     eng = DFlowEngine(n_nodes=3, get_timeout=10.0, sharded=True)
     store = ShardedDStore(eng.nodes, eng.transport)
     rec = TraceRecorder()
-    store.attach_tracer(rec)
+    store.attach_spans(Tracer(recorder=rec))
     runs = []
     for i in range(2):
         wf = Workflow("mid", [
